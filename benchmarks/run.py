@@ -8,8 +8,12 @@ Prints ``name,us_per_call,derived`` CSV rows (benchmarks/common.py).
 
 from __future__ import annotations
 
+import os
 import sys
 import traceback
+from pathlib import Path
+
+import jax
 
 from benchmarks.common import emit
 
@@ -27,6 +31,11 @@ MODULES = [
 
 
 def main() -> None:
+    # JAX reads JAX_COMPILATION_CACHE_DIR itself; without it the cache sits
+    # at one fixed path in the checkout, so later runs find it again
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        root = Path(__file__).resolve().parent.parent
+        jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
     filters = [a for a in sys.argv[1:] if not a.startswith("-")]
     failures = 0
     print("name,us_per_call,derived")

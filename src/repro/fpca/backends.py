@@ -20,7 +20,8 @@ Built-ins (registered at import):
   calibrated bucket-sigmoid + hard-ADC semantics as the fused backends, so
   backends are interchangeable behind one :class:`CompiledFrontend`.
 * ``"pallas"``    — the fused TPU kernel (``interpret=True`` off-TPU;
-  validation only there).
+  validation only there).  On a mesh it runs per device under
+  :func:`data_parallel`.
 * ``"basis"``     — the identical basis-expanded matmul-bank math lowered
   through XLA — the fast deployment path on non-TPU hosts.
 
@@ -39,12 +40,14 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import gating
 from repro.core.adc import ADCConfig, updown_readout
 from repro.core.curvefit import BucketCurvefitModel
 from repro.core.fpca_sim import WeightEncoding, _analog_read, encode_weights, extract_windows
 from repro.core.mapping import FPCASpec, output_dims
+from repro.launch.mesh import data_axes
 
 __all__ = [
     "Backend",
@@ -52,6 +55,7 @@ __all__ = [
     "get_backend",
     "available_backends",
     "default_backend_name",
+    "data_parallel",
 ]
 
 
@@ -103,6 +107,7 @@ class Backend:
         *,
         interpret: bool | None = None,
         m_bucket: int | None = None,
+        mesh: jax.sharding.Mesh | None = None,
     ) -> Callable:
         """A fresh jitted **whole-model** executable: frontend + digital head
         in ONE jit.
@@ -124,6 +129,10 @@ class Backend:
         lowering through ``apply_head`` (same dispatch, traced quant
         pytree); on :attr:`quant_transfer` backends the frontend stage also
         serves the int8 bucket-transfer LUT.
+
+        With a ``mesh`` the frontend stage runs per device under
+        :func:`data_parallel` and the head partitions over the same batch
+        shards.
         """
         kw = {}
         if self.quant_transfer and model_program.precision == "int8":
@@ -137,6 +146,8 @@ class Backend:
             m_bucket=m_bucket,
             **kw,
         )
+        if mesh is not None:
+            frontend = data_parallel(frontend, mesh)
         head = model_program.apply_head
 
         if m_bucket is None:
@@ -209,8 +220,8 @@ class Backend:
         ``FPCAModelProgram.head_out_shape`` says (per-cell detection maps
         included) — the per-tick ``outs["logits"]`` stacks ``K`` of them.
         ``donate=True`` donates the carry buffers (previous frame / ages /
-        previous logits) to the next segment — skip on CPU, where jax does
-        not implement donation.
+        previous logits) to the next segment: the carry passed in is dead
+        after the call.
         """
         adc = adc or ADCConfig()
         enc = enc or WeightEncoding()
@@ -399,6 +410,34 @@ class Backend:
         if donate:
             return jax.jit(run, donate_argnums=(donate_idx,))
         return jax.jit(run)
+
+
+def data_parallel(frontend: Callable, mesh: jax.sharding.Mesh) -> Callable:
+    """Run a frontend executable on every device of ``mesh``, each on its
+    own shard of the batch.
+
+    Mosaic kernels cannot be partitioned by the compiler, so a mesh-served
+    frontend runs under ``shard_map``: images (and the window mask, when
+    the executable takes one) split along the mesh's data axes, weights are
+    replicated, and each device runs the unmodified executable on its rows.
+    The kernel math is row-independent, so no collective is needed and no
+    device ever sees another's windows.  A region-skip executable compacts
+    within its shard, so its ``m_bucket`` counts the rows of one shard.
+    ``check_vma`` is off because a Pallas call's output carries no
+    per-axis variance annotation; every output is batch-sharded anyway.
+    """
+    batch = P(data_axes(mesh))
+
+    def run(images, kernel, bn_offset, *window_mask):
+        return jax.shard_map(
+            frontend,
+            mesh=mesh,
+            in_specs=(batch, P(), P()) + (batch,) * len(window_mask),
+            out_specs=batch,
+            check_vma=False,
+        )(images, kernel, bn_offset, *window_mask)
+
+    return jax.jit(run)
 
 
 _REGISTRY: dict[str, Backend] = {}

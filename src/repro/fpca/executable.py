@@ -37,7 +37,12 @@ from repro.core import gating
 from repro.core.curvefit import BucketCurvefitModel, fit_bucket_model
 from repro.core.mapping import FPCASpec, active_window_mask, output_dims
 from repro.fpca import telemetry
-from repro.fpca.backends import Backend, default_backend_name, get_backend
+from repro.fpca.backends import (
+    Backend,
+    data_parallel,
+    default_backend_name,
+    get_backend,
+)
 from repro.fpca.cache import CacheInfo, CacheInfoVerbose, ExecutableCache
 from repro.fpca.program import FPCAProgram
 from repro.kernels.fpca_conv.ops import StickyBucket, segment_bucket
@@ -122,8 +127,9 @@ class SegmentState:
     hint — the compacted-row bucket the finished segment's kept counts size
     for the next one (:func:`repro.kernels.fpca_conv.ops.segment_bucket`).
     Treat instances as opaque: thread the ``state`` of one
-    :class:`SegmentResult` into the next call.  When the segment ran with
-    buffer donation, the *previous* state's arrays are dead after the call.
+    :class:`SegmentResult` into the next call.  Segments donate their carry
+    by default, so the state passed in is dead after the call; running from
+    it again raises ``ValueError`` (pass ``donate=False`` to keep it).
     """
 
     has_prev: Any
@@ -135,6 +141,16 @@ class SegmentState:
     suggested_bucket: int | None = None
 
     def carry(self, model: bool) -> tuple:
+        if any(
+            isinstance(v, jax.Array) and v.is_deleted()
+            for v in (self.has_prev, self.prev_eff, self.age, self.frame_idx,
+                      self.eff, self.logits)
+        ):
+            raise ValueError(
+                "this SegmentState was donated to an earlier run_segment "
+                "call: thread the state that call returned, or pass "
+                "donate=False to run from one state more than once"
+            )
         c = (
             jnp.asarray(self.has_prev, bool),
             jnp.asarray(self.prev_eff, jnp.float32),
@@ -441,15 +457,19 @@ class CompiledFrontend:
             run = executable_for(None)
             self.stats.windows_executed += m_total
             return run(images, kernel, bn_offset, *extra)[:b]
-        n_keep = int(np.count_nonzero(window_keep))
-        if n_keep == 0:
+        # on a mesh each device compacts its own batch shard, so the row
+        # bucket is sized for the busiest shard
+        n_shards = self.data_parallelism
+        m_shard = m_total // n_shards
+        kept = np.count_nonzero(window_keep.reshape(n_shards, -1), axis=1)
+        if not kept.any():
             # all-skipped tick: the frontend result is exact zeros by
             # contract, so no kernel launches at all (0 executed windows in
             # the stats); the sticky bucket still counts the tick as
             # under-full so a stale large bucket shrinks on the first active
             # tick after the lull
             self.stats.launches_skipped += 1
-            sticky = self._sticky.get(m_total)
+            sticky = self._sticky.get(m_shard)
             if sticky is not None:
                 sticky.observe_idle()
             if empty is not None:
@@ -457,10 +477,11 @@ class CompiledFrontend:
             return jnp.zeros((b, h_o, w_o, c_o), jnp.float32)
         images = self._shard_batch(images)
         self.stats.runs += 1
-        m_bucket = self._bucket_for(n_keep, m_total)
+        m_bucket = self._bucket_for(int(kept.max()), m_shard)
         run = executable_for(m_bucket)
-        self.stats.windows_executed += m_bucket
-        return run(images, kernel, bn_offset, *extra, jnp.asarray(window_keep))[:b]
+        self.stats.windows_executed += m_bucket * n_shards
+        window_keep = self._shard_batch(jnp.asarray(window_keep))
+        return run(images, kernel, bn_offset, *extra, window_keep)[:b]
 
     def stream(
         self,
@@ -572,7 +593,7 @@ class CompiledFrontend:
         gate: Any = _USE_PROGRAM,
         m_bucket: int | None = None,
         early_exit: int | None = None,
-        donate: bool | None = None,
+        donate: bool = True,
     ) -> SegmentResult:
         """Serve ``K`` streaming ticks as ONE device-compiled program.
 
@@ -580,9 +601,10 @@ class CompiledFrontend:
         ages, keyframe cadence, kept-window compaction, zero-kept
         short-circuit — runs inside a single ``jax.lax.scan`` launch, so
         tick latency is kernel-bound instead of dispatch-bound.  Outputs are
-        bit-identical, tick for tick, to the per-tick Python loop (the
-        differential harness in ``tests/test_segment_parity.py`` pins this
-        across backends).
+        bit-identical, tick for tick, to the per-tick Python loop on CPU
+        (the differential harness in ``tests/test_segment_parity.py`` pins
+        this across backends); on a TPU counts and gate decisions stay
+        identical and head logits agree to f32 rounding.
 
         Args:
           frames: ``(K, H, W, c_i)`` stack; ``K`` is static per compiled
@@ -603,7 +625,8 @@ class CompiledFrontend:
             (``lax.while_loop`` variant); ``result.ticks`` reports how far
             the segment got — feed the remaining frames to the next call.
           donate: donate the carry buffers (previous frame / ages / previous
-            logits) to the device call; default on for non-CPU backends.
+            logits) to the device call, so ``state`` is dead afterwards;
+            pass ``False`` to run from one state more than once.
         """
         return self.run_segment_weighted(
             self._require_weights(), self._bn, frames,
@@ -622,7 +645,7 @@ class CompiledFrontend:
         gate: Any = _USE_PROGRAM,
         m_bucket: int | None = None,
         early_exit: int | None = None,
-        donate: bool | None = None,
+        donate: bool = True,
     ) -> SegmentResult:
         """:meth:`run_segment` with explicit weights (the serving-layer
         entry point — weights enter traced, so reprogramming between
@@ -651,7 +674,7 @@ class CompiledFrontend:
         gate: Any,
         m_bucket: int | None,
         early_exit: int | None,
-        donate: bool | None,
+        donate: bool,
         head_params: Any | None,
     ) -> SegmentResult:
         spec = self.spec
@@ -697,8 +720,6 @@ class CompiledFrontend:
                 raise ValueError("early_exit patience must be >= 1")
             if not gated:
                 raise ValueError("early_exit requires a gated segment")
-        if donate is None:
-            donate = jax.default_backend() != "cpu"
         run = self._segment_executable(
             K, m_bucket, gated, early_exit, bool(donate), model=is_model
         )
@@ -706,6 +727,7 @@ class CompiledFrontend:
             state = self._fresh_segment_state(
                 gate.hysteresis if gated else 0, is_model
             )
+        carry = state.carry(is_model)
         first_idx = int(state.frame_idx)
         args: list = [frames, kernel, bn_offset]
         if is_model:
@@ -716,7 +738,7 @@ class CompiledFrontend:
                 jnp.asarray(gate.hysteresis, jnp.int32),
                 jnp.asarray(gate.keyframe_interval, jnp.int32),
             ))
-        args.append(state.carry(is_model))
+        args.append(carry)
         outs, new_carry = run(*args)
         # the per-tick bookkeeping is realised eagerly (it feeds stats and
         # the boundary servo); counts/logits stay lazy for overlap
@@ -870,7 +892,7 @@ class CompiledFrontend:
         if m_bucket is not None and not self.backend.bucket_sensitive:
             m_bucket = -1
         transfer = self._frontend_transfer()
-        key = self._sig + (self.backend.name, m_bucket, transfer)
+        key = self._sig + (self.backend.name, m_bucket, transfer, self.mesh)
 
         def build() -> Callable:
             # a FRESH jitted closure per signature: its compiled programs are
@@ -878,18 +900,18 @@ class CompiledFrontend:
             # executable (a shared module-level jit cache would keep them
             # alive).
             kw = {"transfer": transfer} if transfer != "f32" else {}
-            return self.backend.instrumented(
-                self.backend.make_executable(
-                    self.model,
-                    spec=self.spec,
-                    adc=self.program.adc,
-                    enc=self.program.enc,
-                    interpret=self.interpret,
-                    m_bucket=m_bucket,
-                    **kw,
-                ),
-                site="frontend",
+            run = self.backend.make_executable(
+                self.model,
+                spec=self.spec,
+                adc=self.program.adc,
+                enc=self.program.enc,
+                interpret=self.interpret,
+                m_bucket=m_bucket,
+                **kw,
             )
+            if self.mesh is not None:
+                run = data_parallel(run, self.mesh)
+            return self.backend.instrumented(run, site="frontend")
 
         return self._cache.get(key, build)
 
@@ -1172,7 +1194,7 @@ class CompiledModel(CompiledFrontend):
         gate: Any = _USE_PROGRAM,
         m_bucket: int | None = None,
         early_exit: int | None = None,
-        donate: bool | None = None,
+        donate: bool = True,
     ) -> "SegmentResult":
         """Model variant of :meth:`CompiledFrontend.run_segment_weighted`:
         the per-tick head pass (skip-aware effective-map patch + logits) runs
@@ -1225,7 +1247,7 @@ class CompiledModel(CompiledFrontend):
     def _model_executable(self, m_bucket: int | None) -> Callable:
         if m_bucket is not None and not self.backend.bucket_sensitive:
             m_bucket = -1
-        key = self._model_sig + (self.backend.name, "model", m_bucket)
+        key = self._model_sig + (self.backend.name, "model", m_bucket, self.mesh)
 
         def build() -> Callable:
             return self.backend.instrumented(
@@ -1234,6 +1256,7 @@ class CompiledModel(CompiledFrontend):
                     self.model,
                     interpret=self.interpret,
                     m_bucket=m_bucket,
+                    mesh=self.mesh,
                 ),
                 site="model",
             )
@@ -1313,7 +1336,9 @@ def compile(  # noqa: A001  (torch.compile-style public name)
         instance; ``None`` auto-selects by platform (Pallas on TPU, the XLA
         basis form elsewhere).
       mesh: optional ``jax.sharding.Mesh`` — batches shard over its data
-        axes and batch padding rounds up to the data-axis extent.
+        axes and batch padding rounds up to the data-axis extent; each
+        device runs the frontend on its own shard
+        (:func:`repro.fpca.backends.data_parallel`).
       weights / bn_offset: optionally program the NVM planes immediately
         (equivalent to calling :meth:`CompiledFrontend.reprogram`).
       model: fitted :class:`BucketCurvefitModel`; fitted on demand from

@@ -20,7 +20,6 @@ from pathlib import Path
 
 import jax
 
-from repro import compat
 from repro.configs import ARCHS, SHAPES, shape_applicable
 from repro.launch.cells import CellPlan, build_cell
 from repro.launch.mesh import make_production_mesh
@@ -46,7 +45,7 @@ def run_fpca_cell(
     t0 = time.time()
     import jax.numpy as jnp
 
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         jitted, args, info = build_fpca_cell(
             shape, mesh, model,
             fuse_phases=fuse_phases,
@@ -109,7 +108,7 @@ def run_cell(
     t0 = time.time()
     # set_mesh: in-graph sharding constraints (e.g. the vocab reshard in
     # layers.unembed) need the ambient abstract mesh during tracing.
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         jitted, args = build_cell(cfg, shape, mesh, plan)
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0

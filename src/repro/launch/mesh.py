@@ -16,8 +16,6 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from repro import compat
-
 __all__ = ["make_production_mesh", "make_host_mesh", "data_axes", "data_extent"]
 
 
@@ -25,7 +23,9 @@ def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) == n:
-        return compat.make_mesh(shape, axes)
+        return jax.make_mesh(
+            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+        )
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices for mesh {dict(zip(axes, shape))}, have {len(devices)} — "

@@ -142,10 +142,9 @@ def summarize_cell(
     scan body once, which under-reports a scanned-layers transformer by the
     trip count — both raw views are recorded.
     """
-    from repro.compat import cost_analysis_dict
     from repro.launch.hlo_analysis import analyze_hlo
 
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     hlo = analyze_hlo(compiled.as_text(), world)
     flops = float(hlo.flops)

@@ -14,8 +14,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 __all__ = [
     "rms_norm",
     "init_rms_norm",
@@ -88,8 +86,8 @@ def maybe_shard(x: jax.Array, *spec) -> jax.Array:
 
     Axis names in ``spec`` that don't exist in the ambient mesh are dropped
     (so the same model code lowers under 2-axis and 3-axis meshes)."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
 
     def clean(entry):

@@ -1252,8 +1252,8 @@ class StreamServer:
             # host path recomputes head(eff) each tick, which is the same bits
             handle = self.pipeline.model_handle_for(cfg.model)
             state.logits = handle.head_logits(
-                eff, head_params=cfg.head_params
-            )
+                jnp.asarray(eff)[None], head_params=cfg.head_params
+            )[0]
         return state
 
     def serve_segments(

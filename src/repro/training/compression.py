@@ -62,7 +62,6 @@ def sync_grads_compressed(grads: Any, error: Any, mesh, axes: tuple[str, ...]):
     The int8 payload crosses the wire; the mean happens in f32 after
     dequantisation (psum of int8 payloads would overflow).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     g_hat, new_e, metrics = compress_decompress(grads, error)
@@ -77,5 +76,5 @@ def sync_grads_compressed(grads: Any, error: Any, mesh, axes: tuple[str, ...]):
         return jax.tree.map(lambda x: jax.lax.psum(x, axes) / n, g)
 
     spec = jax.tree.map(lambda _: P(), g_hat)
-    synced = shard_map(mean_fn, mesh=mesh, in_specs=(spec,), out_specs=spec)(g_hat)
+    synced = jax.shard_map(mean_fn, mesh=mesh, in_specs=(spec,), out_specs=spec)(g_hat)
     return synced, new_e, metrics

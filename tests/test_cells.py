@@ -7,7 +7,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.configs import ARCHS, reduce_for_smoke
 from repro.configs.base import ShapeSpec
 from repro.launch.cells import CellPlan, build_cell
@@ -36,7 +35,7 @@ def _mesh():
 def test_cell_compiles_on_host_mesh(arch, shape):
     cfg = reduce_for_smoke(ARCHS[arch])
     mesh = _mesh()
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         jitted, args = build_cell(cfg, shape, mesh, CellPlan(remat="none"))
         compiled = jitted.lower(*args).compile()
     rec = summarize_cell(compiled, cfg, shape, mesh.size)

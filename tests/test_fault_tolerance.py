@@ -12,6 +12,7 @@ import pytest
 
 from repro.configs import ARCHS, reduce_for_smoke
 from repro.data.pipeline import LMStreamConfig, PrefetchIterator, SyntheticLM, SyntheticVWW
+from repro.launch.mesh import make_host_mesh
 from repro.models.transformer import init_model
 from repro.training.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from repro.training.compression import compress_decompress, init_error_state
@@ -74,9 +75,7 @@ def test_elastic_resharding(tmp_path):
 
     params, _ = _state()
     save_checkpoint(tmp_path, 3, params)
-    from repro import compat
-
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     shardings = jax.tree.map(
         lambda p: NamedSharding(mesh, P(*([None] * p.ndim))), params
     )

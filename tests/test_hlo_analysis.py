@@ -8,7 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import cost_analysis_dict
 from repro.launch.hlo_analysis import analyze_hlo
 
 D = 128
@@ -37,7 +36,7 @@ def test_scan_flops_match_unrolled_cost_analysis():
     sds = jax.ShapeDtypeStruct((D, D), jnp.float32)
     c_scan = _compile(scanned, sds)
     c_unroll = _compile(unrolled, sds)
-    want = cost_analysis_dict(c_unroll)["flops"]
+    want = c_unroll.cost_analysis()["flops"]
     got = analyze_hlo(c_scan.as_text(), world=1).flops
     assert got == pytest.approx(want, rel=0.01), (got, want)
 

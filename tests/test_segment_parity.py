@@ -517,22 +517,37 @@ def test_server_segment_mode_matches_per_tick(bucket_model):
         np.testing.assert_array_equal(a.block_mask, b.block_mask)
 
 
-def test_server_interleaves_tick_and_segment_modes(bucket_model):
-    """tick -> segment -> tick on ONE stream stays bit-identical to pure
-    per-tick serving (absorb_segment rebuilds the host mirror)."""
-    frames = _frames(9, static=(2, 5))
-    srv_ref = StreamServer(_pipeline(bucket_model), GATE)
+def _model_pipeline(bucket_model) -> FPCAPipeline:
+    mp = _model_handle(bucket_model).model_program
+    pipe = FPCAPipeline(bucket_model, backend="basis", interpret=True)
+    pipe.register("cam", mp, _kernel(), head_params=mp.init_head(
+        jax.random.PRNGKey(0)))
+    return pipe
+
+
+@pytest.mark.parametrize("make_pipeline", [_pipeline, _model_pipeline],
+                         ids=["frontend", "model"])
+def test_server_interleaves_tick_and_segment_modes(bucket_model, make_pipeline):
+    """tick -> segment -> segment -> tick on ONE stream stays bit-identical
+    to pure per-tick serving (absorb_segment rebuilds the host mirror; the
+    first segment seeds its carry, model head included, from the per-tick
+    state, and the second runs on the donated carry of the first)."""
+    frames = _frames(11, static=(2, 5))
+    srv_ref = StreamServer(make_pipeline(bucket_model), GATE)
     srv_ref.add_stream("cam0", "cam")
     ref = list(srv_ref.serve("cam0", frames))
-    srv = StreamServer(_pipeline(bucket_model), GATE)
+    srv = StreamServer(make_pipeline(bucket_model), GATE)
     srv.add_stream("cam0", "cam")
     got = list(srv.serve("cam0", frames[:3]))
     got += srv.run_segment("cam0", frames[3:6])
-    got += list(srv.serve("cam0", frames[6:]))
+    got += srv.run_segment("cam0", frames[6:8])
+    got += list(srv.serve("cam0", frames[8:]))
     assert [r.frame_idx for r in got] == [r.frame_idx for r in ref]
     for a, b in zip(got, ref):
         np.testing.assert_array_equal(a.counts, b.counts)
         assert a.kept_windows == b.kept_windows
+        if b.logits is not None:
+            np.testing.assert_array_equal(a.logits, b.logits)
 
 
 def test_boundary_servo_steps_once_per_segment(bucket_model):
@@ -591,11 +606,28 @@ def test_segment_bucket_suggestion_threads_to_next_segment(bucket_model):
     assert s1.state.suggested_bucket is not None
     assert s1.state.suggested_bucket >= 1
     ref = fe.run_segment(frames[6:], state=dataclasses.replace(
-        s1.state, suggested_bucket=None))
+        s1.state, suggested_bucket=None), donate=False)
     s2 = fe.run_segment(frames[6:], state=s1.state)   # uses the suggestion
     np.testing.assert_array_equal(
         np.asarray(s2.counts), np.asarray(ref.counts)
     )
+
+
+def test_segment_donated_state_is_consumed(bucket_model):
+    """Segments donate their carry by default (on every platform): the
+    state passed in is dead afterwards, and running from it again fails
+    with a named error instead of reading a deleted buffer."""
+    fe = _fe(bucket_model, "basis")
+    frames = _scene(8, seed=6)
+    s1 = fe.run_segment(frames[:4])
+    kept = fe.run_segment(frames[4:], state=s1.state, donate=False)
+    s2 = fe.run_segment(frames[4:], state=s1.state)
+    np.testing.assert_array_equal(np.asarray(s2.counts), np.asarray(kept.counts))
+    assert s1.state.prev_eff.is_deleted()
+    with pytest.raises(ValueError, match="donated"):
+        fe.run_segment(frames[4:], state=s1.state)
+    # the returned state threads on
+    assert fe.run_segment(frames[4:], state=s2.state).first_frame_idx == 8
 
 
 def test_frontend_stats_count_segments(bucket_model):
