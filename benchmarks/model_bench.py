@@ -137,8 +137,7 @@ def run() -> list[Row]:
     # telemetry lane: same scan workload with a live session (uploaded by
     # the CI bench-smoke job next to the stream bench's JSONL)
     telemetry.enable(
-        TELEMETRY_JSONL, device_time_rate=4,
-        run_labels={"bench": "model_scan_segment"},
+        TELEMETRY_JSONL, run_labels={"bench": "model_scan_segment"},
     )
     t_scan_tel, tel_server = _serve_scan(m_bucket=scan_bucket)
     fleet = fleet_report(tel_server)

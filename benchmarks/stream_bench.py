@@ -145,12 +145,11 @@ def run() -> list[Row]:
     )
     fps_scan = N_FRAMES * N_STREAMS / t_scan
 
-    # telemetry lane: the SAME scan workload with a live session (JSONL +
-    # sampled honest device time) — what the CI bench-smoke job uploads —
+    # telemetry lane: the SAME scan workload with a live session (JSONL
+    # spans) — what the CI bench-smoke job uploads —
     # plus the zero-overhead-when-disabled guard for the hot tick path
     telemetry.enable(
-        TELEMETRY_JSONL, device_time_rate=4,
-        run_labels={"bench": "stream_scan_segment"},
+        TELEMETRY_JSONL, run_labels={"bench": "stream_scan_segment"},
     )
     t_scan_tel, tel_server = _serve_scan(
         pipe_flap, frame_stacks, m_bucket=scan_bucket

@@ -74,8 +74,7 @@ def main() -> None:
     cam = SyntheticMovingObject((H, W), seed=1, radius=12.0)
 
     jsonl = Path(tempfile.gettempdir()) / "adaptive_stream_telemetry.jsonl"
-    telemetry.enable(jsonl, device_time_rate=8,
-                     run_labels={"example": "adaptive_stream"})
+    telemetry.enable(jsonl, run_labels={"example": "adaptive_stream"})
 
     print(f"\nservoing gate threshold to a {TARGET:.0%} kept-window budget:")
     print(f"{'tick':>4} {'threshold':>10} {'kept EMA':>9}  configs served")

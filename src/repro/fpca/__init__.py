@@ -36,7 +36,7 @@ Layer map:
   :class:`repro.models.heads.HeadGraph` head graphs;
 * :mod:`repro.fpca.telemetry`  — the process-wide metrics registry every
   stats object reports into, span traces
-  (``telemetry.enable(jsonl_path=...)``) and opt-in device-profile hooks.
+  (``telemetry.enable(jsonl_path=...)``) and opt-in profiler annotations.
 
 The batch scheduler (:class:`repro.serving.fpca_pipeline.FPCAPipeline`) and
 the streaming fleet server (:class:`repro.serving.streaming.StreamServer`)

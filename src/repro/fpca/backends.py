@@ -88,10 +88,9 @@ class Backend:
     description: str = ""
 
     def instrumented(self, fn: Callable, *, site: str) -> Callable:
-        """Wrap a jitted closure with the opt-in device-profile hooks
-        (:func:`repro.fpca.telemetry.instrument_launch`): launch counting,
-        ``jax.profiler.TraceAnnotation`` tagging and rate-limited
-        ``block_until_ready`` device-time sampling, all labeled
+        """Wrap a jitted closure with the opt-in launch hooks
+        (:func:`repro.fpca.telemetry.instrument_launch`): launch counting
+        and ``jax.profiler.TraceAnnotation`` tagging, labeled
         ``{site, backend}``.  :class:`repro.fpca.CompiledFrontend` routes
         every cache-built executable through this, so third-party backends
         registered via :func:`register_backend` are covered uniformly.

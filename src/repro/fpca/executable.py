@@ -93,6 +93,8 @@ class FrontendStats(telemetry.StatsView):
     * ``bucket_shrinks_deferred`` — flap events sticky hysteresis absorbed
     * ``segments``          — device-compiled segment launches
     * ``segment_ticks``     — ticks served from inside those launches
+    * ``h2d_bytes``         — ``nbytes`` of the host arrays a weighted call
+      copies to the device (a host image batch, the keep grid)
 
     When the handle is owned by a :class:`repro.serving.FPCAPipeline` the
     cells are parent-chained into the pipeline's ``PipelineStats`` (same
@@ -114,6 +116,7 @@ class FrontendStats(telemetry.StatsView):
         "bucket_shrinks_deferred",
         "segments",
         "segment_ticks",
+        "h2d_bytes",
     )
 
 
@@ -423,7 +426,10 @@ class CompiledFrontend:
         """
         executable_for = executable_for or self._executable
         spec = self.spec
+        host_images = not isinstance(images, jax.Array)
         images = jnp.asarray(images, jnp.float32)
+        if host_images:
+            self.stats.h2d_bytes += images.nbytes
         want = (spec.image_h, spec.image_w, spec.in_channels)
         if images.ndim != 4 or images.shape[1:] != want:
             raise ValueError(
@@ -480,6 +486,8 @@ class CompiledFrontend:
         m_bucket = self._bucket_for(int(kept.max()), m_shard)
         run = executable_for(m_bucket)
         self.stats.windows_executed += m_bucket * n_shards
+        if isinstance(window_keep, np.ndarray):
+            self.stats.h2d_bytes += window_keep.nbytes
         window_keep = self._shard_batch(jnp.asarray(window_keep))
         return run(images, kernel, bn_offset, *extra, window_keep)[:b]
 

@@ -1,4 +1,4 @@
-"""Process-wide telemetry: metrics registry, span traces, device hooks.
+"""Process-wide telemetry: metrics registry, span traces, profiler hooks.
 
 One substrate behind every stats surface in the stack.  The ad-hoc counter
 objects (``FrontendStats``, ``PipelineStats``, ``StreamStats``) are thin
@@ -14,17 +14,19 @@ Three export surfaces:
 
 * ``registry().render()``   — Prometheus-style text snapshot.
 * ``enable(jsonl_path=...)``— structured JSONL event log (spans, servo
-  actuations, device-time samples), strict RFC 8259 JSON (no NaN/Infinity;
+  actuations), strict RFC 8259 JSON (no NaN/Infinity;
   ``benchmarks/_util.py`` delegates to :func:`jsonable` here).
 * ``repro.serving.observe.fleet_report()`` — per-(stream, config) table.
 
 Everything is zero-overhead when disabled: ``span()`` returns one shared
 null context manager (no allocation), launch wrappers are a single
 ``is None`` check, and no hot-path code builds dicts or syncs the device
-unless a session is active.  Device-profile hooks
-(``jax.profiler.TraceAnnotation`` + sampled ``block_until_ready`` for
-honest device time) are opt-in per session and rate-limited so
-steady-state dispatch stays non-blocking.
+unless a session is active.  With ``profile=True`` on the session, every
+span and every instrumented launch also opens a
+``jax.profiler.TraceAnnotation`` (``fpca:<span>``,
+``fpca:<site>:<backend>``), so a ``jax.profiler`` trace shows the host
+phases on the same clock as the device operations; device time is read
+from that trace.  Nothing here ever syncs the device.
 """
 
 from __future__ import annotations
@@ -491,16 +493,12 @@ class StatsView:
 
 
 class TelemetrySession:
-    """One enabled telemetry run: JSONL sink + device-hook policy."""
+    """One enabled telemetry run: JSONL sink + profiler-annotation policy."""
 
     def __init__(self, jsonl_path: Path | str | None = None, *,
-                 profile: bool = False, device_time_rate: int = 0,
-                 run_labels: dict | None = None):
+                 profile: bool = False, run_labels: dict | None = None):
         self.jsonl_path = Path(jsonl_path) if jsonl_path else None
         self.profile = bool(profile)
-        # sample honest device time (block_until_ready) on every Nth
-        # instrumented launch; 0 disables blocking entirely.
-        self.device_time_rate = int(device_time_rate)
         self.run_labels = dict(run_labels or {})
         self.events_written = 0
         self._fh = None
@@ -542,22 +540,22 @@ _SESSION: TelemetrySession | None = None
 
 
 def enable(jsonl_path: Path | str | None = None, *,
-           profile: bool = False, device_time_rate: int = 0,
+           profile: bool = False,
            run_labels: dict | None = None) -> TelemetrySession:
-    """Turn telemetry on for the process (spans, JSONL, device hooks).
+    """Turn telemetry on for the process (spans, JSONL, profiler tags).
 
     Counters in stats views are *always* live (they are plain attribute
     adds); what ``enable`` switches on is the expensive part: span timing,
-    JSONL event emission, and the opt-in device-profile hooks
-    (``profile=True`` wraps launches in ``jax.profiler.TraceAnnotation``;
-    ``device_time_rate=N`` blocks on every Nth launch for honest device
-    time — leave 0 to never sync).
+    JSONL event emission and, with ``profile=True``, a
+    ``jax.profiler.TraceAnnotation`` around every span (``fpca:<name>``)
+    and instrumented launch (``fpca:<site>:<backend>``).  For device time,
+    run a ``jax.profiler`` trace with ``profile=True``: the annotations and
+    the device operations share its clock.
     """
     global _SESSION
     if _SESSION is not None:
         _SESSION.close()
     _SESSION = TelemetrySession(jsonl_path, profile=profile,
-                                device_time_rate=device_time_rate,
                                 run_labels=run_labels)
     return _SESSION
 
@@ -602,29 +600,37 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "fields", "t0", "_session")
+    __slots__ = ("name", "fields", "t0", "_session", "_annotation")
 
     def __init__(self, sess: TelemetrySession, name: str,
                  fields: dict | None):
         self.name = name
         self.fields = fields
         self._session = sess
-        self.t0 = 0.0
+        self.t0 = 0
+        self._annotation = None
 
     def __enter__(self):
+        if self._session.profile:
+            import jax
+
+            self._annotation = jax.profiler.TraceAnnotation(f"fpca:{self.name}")
+            self._annotation.__enter__()
         _LOCAL.stack.append(self.name)
-        self.t0 = time.perf_counter()
+        self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self.t0
+        dt = (time.perf_counter_ns() - self.t0) * 1e-9
         stack = _LOCAL.stack
         stack.pop()
         parent = stack[-1] if stack else None
         _SPAN_HIST.labels(span=self.name).observe(dt)
         self._session.event(
             "span", span=self.name, dur_s=dt, parent=parent,
-            depth=len(stack), **(self.fields or {}))
+            depth=len(stack), t0_ns=self.t0, **(self.fields or {}))
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
 
@@ -640,7 +646,12 @@ def span(name: str, fields: dict | None = None):
     global ``is None`` check and nothing else.  ``fields`` is a plain
     optional dict (not ``**kwargs``) so a disabled-mode call in a tick hot
     path allocates nothing; hot call sites prebuild their label dict once
-    per stream and pass the same object every tick."""
+    per stream and pass the same object every tick.
+
+    Enabled, the span's JSONL record carries ``span``, ``dur_s``,
+    ``parent``, ``depth``, ``t0_ns`` (``time.perf_counter_ns()`` at entry)
+    and ``fields``; with ``profile=True`` the span also runs under
+    ``jax.profiler.TraceAnnotation(f"fpca:{name}")``."""
     s = _SESSION
     if s is None:
         return _NULL_SPAN
@@ -648,54 +659,35 @@ def span(name: str, fields: dict | None = None):
 
 
 # --------------------------------------------------------------------------
-# device-profile hooks
+# launch hooks
 
 
 _LAUNCHES = _REGISTRY.counter(
     "fpca_launches_total", "instrumented executable invocations",
     ("site", "backend"), max_label_sets=128)
-_DEVICE_SECONDS = _REGISTRY.histogram(
-    "fpca_device_seconds", "sampled honest device time per launch "
-    "(block_until_ready)", ("site", "backend"), max_label_sets=128)
 
 
 def instrument_launch(fn: Callable, *, site: str, backend: str) -> Callable:
-    """Wrap a jitted executable with the opt-in device-profile hooks.
+    """Wrap a jitted executable with the opt-in launch hooks.
 
     Disabled mode costs one module-global ``is None`` check per call.
     Enabled mode counts the launch; with ``profile=True`` on the session it
-    runs under ``jax.profiler.TraceAnnotation`` (visible in TensorBoard /
-    perfetto traces); with ``device_time_rate=N`` every Nth call blocks on
-    the result for an honest device-time sample (steady-state calls stay
-    non-blocking).
+    runs under ``jax.profiler.TraceAnnotation`` (``fpca:<site>:<backend>``,
+    visible in TensorBoard / perfetto traces).  The launch never blocks.
     """
     counter = _LAUNCHES.labels(site=site, backend=backend)
-    hist = _DEVICE_SECONDS.labels(site=site, backend=backend)
     tag = f"fpca:{site}:{backend}"
-    state = {"n": 0}
 
     def launch(*args, **kwargs):
         s = _SESSION
         if s is None:
             return fn(*args, **kwargs)
         counter.add(1)
-        state["n"] += 1
         if s.profile:
             import jax
             with jax.profiler.TraceAnnotation(tag):
-                out = fn(*args, **kwargs)
-        else:
-            out = fn(*args, **kwargs)
-        rate = s.device_time_rate
-        if rate > 0 and state["n"] % rate == 0:
-            import jax
-            t0 = time.perf_counter()
-            jax.block_until_ready(out)
-            dt = time.perf_counter() - t0
-            hist.observe(dt)
-            s.event("device_time", site=site, backend=backend, dur_s=dt,
-                    launch=state["n"])
-        return out
+                return fn(*args, **kwargs)
+        return fn(*args, **kwargs)
 
     launch.__wrapped__ = fn
     launch._fpca_site = site

@@ -237,6 +237,7 @@ def fpca_conv_pallas(
         out_specs=pl.BlockSpec((block_m, block_c), lambda m, c: (m, c)),
         out_shape=jax.ShapeDtypeStruct((Mp, Cp), jnp.float32),
         interpret=interpret,
+        name="fpca_conv",
     )(
         patches_p,
         mask[:, None].astype(jnp.float32),

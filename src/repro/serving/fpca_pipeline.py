@@ -129,6 +129,8 @@ class PipelineStats(telemetry.StatsView):
       ONE cell and the delta lands here too, replacing the old before/after
       delta-mirroring (which double-counted by construction if a call path
       mirrored twice, and missed direct handle use entirely).
+    * ``h2d_bytes``      — parent-chained likewise: host bytes the handles
+      copied to the device (host image batches, keep grids)
 
     ``cache_hits`` / ``cache_misses`` / ``evictions`` are **derived** reads
     of the shared :class:`repro.fpca.ExecutableCache` — the same counters
@@ -148,6 +150,7 @@ class PipelineStats(telemetry.StatsView):
         "bucket_shrinks_deferred",
         "segments",
         "segment_ticks",
+        "h2d_bytes",
     )
     _DERIVED = ("cache_hits", "cache_misses", "evictions")
 

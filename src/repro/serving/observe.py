@@ -256,7 +256,7 @@ def assert_reconciled(pipeline, server=None) -> None:
             )
     chained = ("windows_total", "windows_executed", "launches_skipped",
                "bucket_switches", "bucket_shrinks_deferred",
-               "segments", "segment_ticks")
+               "segments", "segment_ticks", "h2d_bytes")
     handles = [
         h for h in pipeline._handles.values()
         if isinstance(getattr(h, "stats", None), telemetry.StatsView)

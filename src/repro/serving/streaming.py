@@ -251,6 +251,9 @@ class StreamSession:
     follows the same shape with :class:`GateController` instances.  With
     controllers attached, every gated frame feeds the closed-loop threshold
     servo(s) and the per-config gates are re-derived for the next frame.
+
+    ``stats`` (the owning server's :class:`StreamStats`) is billed the
+    host↔device bytes of the gate dispatches this session makes itself.
     """
 
     def __init__(
@@ -261,8 +264,10 @@ class StreamSession:
         gate: DeltaGateConfig | Mapping[str, DeltaGateConfig] | None,
         history: int = 512,
         controller: GateController | Mapping[str, GateController] | None = None,
+        stats: "StreamStats | None" = None,
     ):
         self.stream_id = stream_id
+        self.stats = stats
         self.configs: tuple[str, ...] = (
             (config,) if isinstance(config, str) else tuple(config)
         )
@@ -404,18 +409,21 @@ class StreamSession:
             delta_blocks = np.asarray(precomputed[1])
         elif self._prev is None:
             kernels = gating.host_gate_kernels(self.spec)
-            cur = np.asarray(kernels.eff(np.asarray(frame, np.float32)))
+            frame = np.asarray(frame, np.float32)
+            cur = np.asarray(kernels.eff(frame))
+            self._bill(frame.nbytes, cur.nbytes)
         else:
             # ONE fused dispatch per tick (effective frame + block delta):
             # the gate result is needed synchronously to build this tick's
             # window mask, so per-call overhead sits on the serving hot loop
             kernels = gating.host_gate_kernels(self.spec)
-            cur_d, delta_d = kernels.step(
-                np.asarray(self._prev, np.float32),
-                np.asarray(frame, np.float32),
-            )
+            prev = np.asarray(self._prev, np.float32)
+            frame = np.asarray(frame, np.float32)
+            cur_d, delta_d = kernels.step(prev, frame)
             cur = np.asarray(cur_d)
             delta_blocks = np.asarray(delta_d)
+            self._bill(prev.nbytes + frame.nbytes,
+                       cur.nbytes + delta_blocks.nbytes)
         if self.want_events:
             # polarity source for the event tap: signed block-mean change,
             # captured before ``_prev`` is overwritten below
@@ -440,6 +448,11 @@ class StreamSession:
         self.frame_idx += 1
         self.last_window_mask = union_window
         return union_keep
+
+    def _bill(self, h2d: int, d2h: int) -> None:
+        if self.stats is not None:
+            self.stats.h2d_bytes += h2d
+            self.stats.d2h_bytes += d2h
 
     def absorb_segment(self, seg) -> None:
         """Fold one finished device-compiled segment into this session.
@@ -578,6 +591,10 @@ class StreamStats(telemetry.StatsView):
     ``serve_seconds`` accumulates wall-clock time spent
     in the serving loop (dispatch + realisation) — the denominator
     :func:`repro.serving.observe.fleet_report` derives fps from.
+    ``h2d_bytes`` / ``d2h_bytes`` count the bytes the per-tick path moves
+    across the host↔device boundary: ``nbytes`` of every host array that
+    enters a device call (gate inputs, frames, keep grids) and of every
+    device array it realises on the host (gate results, counts, logits).
 
     The server deliberately does NOT parent-chain into the pipeline's
     stats: it is a scoped observer of a *shared* pipeline (other callers
@@ -598,6 +615,8 @@ class StreamStats(telemetry.StatsView):
         "segment_ticks",
         "fused_head_calls",
         "serve_seconds",
+        "h2d_bytes",
+        "d2h_bytes",
     )
 
 
@@ -653,8 +672,8 @@ class StreamServer:
         self.sessions: dict[str, StreamSession] = {}
         self.event_taps: dict[str, Any] = {}
         self.stats = StreamStats()
-        # prebuilt span label dicts (one per server / per stream) so an
-        # enabled-telemetry tick allocates no dicts on the hot loop
+        # prebuilt span label dicts (one per server / per stream); only an
+        # enabled-telemetry tick builds its own, to add the tick index
         self._span_fields = {"server": self.stats._labels["instance"]}
         self._seg_fields: dict[str, dict] = {}
 
@@ -750,7 +769,8 @@ class StreamServer:
             }
             ctl_map = {n: _controller_for(gate_map[n], n) for n in names}
             session = StreamSession(
-                stream_id, names, spec, gate_map, controller=ctl_map
+                stream_id, names, spec, gate_map, controller=ctl_map,
+                stats=self.stats,
             )
         else:
             ctl = (
@@ -759,7 +779,8 @@ class StreamServer:
                 else None
             )
             session = StreamSession(
-                stream_id, names, spec, eff_gate, controller=ctl
+                stream_id, names, spec, eff_gate, controller=ctl,
+                stats=self.stats,
             )
         self.sessions[stream_id] = session
         self._seg_fields[stream_id] = {"stream": stream_id}
@@ -780,7 +801,10 @@ class StreamServer:
     # -- serving loop --------------------------------------------------------
     def _dispatch(self, frames: Mapping[str, Any]) -> list[dict]:
         """Host side of one tick: gate every stream, fan streams into one
-        batch per configuration group, dispatch without blocking."""
+        batch per configuration group, dispatch without blocking.
+
+        Each group's work runs under the telemetry spans ``gate``,
+        ``stage``, ``frontend`` and ``head``, in that order."""
         per_group: dict[tuple[str, ...], list[tuple[StreamSession, np.ndarray]]] = {}
         for stream_id, frame in frames.items():
             session = self.sessions.get(stream_id)
@@ -794,92 +818,108 @@ class StreamServer:
             pstats.bucket_switches,
             pstats.bucket_shrinks_deferred,
             pstats.launches_skipped,
+            pstats.h2d_bytes,
         )
         launches: list[dict] = []
         for configs, members in per_group.items():
             spec = members[0][0].spec
             h_o, w_o = mapping.output_dims(spec)
-            entries = []
-            keeps = []
             gated = any(session.gating for session, _ in members)
-            # fleet-batched host gating: every warmed-up gated stream of the
-            # group computes its effective frame + block |Δ| grid in ONE
-            # vmapped dispatch (bit-identical to the solo kernel), so the
-            # per-tick host cost stays flat as the fleet grows; first-frame
-            # and dense streams fall through to the per-stream path
-            pre: dict[int, tuple[np.ndarray, np.ndarray]] = {}
-            rows = [
-                i for i, (s, _) in enumerate(members)
-                if s.gating and s._prev is not None
-            ]
-            if len(rows) > 1:
-                kern = gating.host_gate_kernels(spec)
-                curs, deltas = kern.step_batch(
-                    np.stack([
-                        np.asarray(members[i][0]._prev, np.float32)
-                        for i in rows
-                    ]),
-                    np.stack([
-                        np.asarray(members[i][1], np.float32) for i in rows
-                    ]),
+            with telemetry.span("gate"):
+                entries, keeps = self._gate_group(members, spec, h_o, w_o, gated)
+            with telemetry.span("stage"):
+                host_images = np.stack([frame for _, frame in members])
+                keep = np.stack(keeps) if gated else None
+                images = jnp.asarray(host_images)
+                self.stats.h2d_bytes += host_images.nbytes
+            with telemetry.span("frontend"):
+                counts = self.pipeline.run_config_batch(
+                    configs[0] if len(configs) == 1 else list(configs),
+                    images,
+                    keep,
                 )
-                curs, deltas = np.asarray(curs), np.asarray(deltas)
-                pre = {i: (curs[j], deltas[j]) for j, i in enumerate(rows)}
-            for row, (session, frame) in enumerate(members):
-                frame_idx = session.frame_idx
-                block = session.step(frame, precomputed=pre.get(row))
-                window = session.last_window_mask if session.gating else None
-                kept = int(window.sum()) if window is not None else h_o * w_o
-                entry = {
-                    "stream_id": session.stream_id,
-                    "frame_idx": frame_idx,
-                    "block_mask": block,
-                    "kept": kept,
-                    "total": h_o * w_o,
-                }
-                if session.per_config:
-                    entry["per_config"] = {
-                        st.name: (
-                            st.last_block_mask,
-                            int(st.last_window_mask.sum()),
-                            st.last_window_mask,
-                        )
-                        for st in session._states
-                    }
-                tap = self.event_taps.get(session.stream_id)
-                if tap is not None:
-                    # emit this tick's address-event packet from the gate
-                    # state session.step() just wrote (same changed array the
-                    # gate counted — the reconciliation contract)
-                    entry["events"] = tap.observe_tick(frame_idx)
-                entries.append(entry)
-                if gated:
-                    keeps.append(
-                        window
-                        if window is not None
-                        else np.ones((h_o, w_o), bool)
-                    )
-                self.stats.frames += 1
-                self.stats.windows_total += h_o * w_o
-                self.stats.windows_kept += kept
-            images = np.stack([frame for _, frame in members])
-            counts = self.pipeline.run_config_batch(
-                configs[0] if len(configs) == 1 else list(configs),
-                images,
-                np.stack(keeps) if gated else None,
-            )
             slices = (
                 self.pipeline.config_channel_slices(configs)
                 if len(configs) > 1
                 else [(configs[0], None, None)]
             )
             launch = {"counts": counts, "entries": entries, "slices": slices}
-            self._model_head_pass(launch, members, h_o, w_o)
+            with telemetry.span("head"):
+                self._model_head_pass(launch, members, h_o, w_o)
             launches.append(launch)
         self.stats.bucket_switches += pstats.bucket_switches - before[0]
         self.stats.bucket_shrinks_deferred += pstats.bucket_shrinks_deferred - before[1]
         self.stats.launches_skipped += pstats.launches_skipped - before[2]
+        self.stats.h2d_bytes += pstats.h2d_bytes - before[3]
         return launches
+
+    def _gate_group(
+        self, members: list, spec: mapping.FPCASpec, h_o: int, w_o: int,
+        gated: bool,
+    ) -> tuple[list[dict], list[np.ndarray]]:
+        """Gate every stream of one configuration group; returns the
+        group's result entries and (``gated``) its window keep grids."""
+        entries = []
+        keeps = []
+        # fleet-batched host gating: every warmed-up gated stream of the
+        # group computes its effective frame + block |Δ| grid in ONE
+        # vmapped dispatch (bit-identical to the solo kernel), so the
+        # per-tick host cost stays flat as the fleet grows; first-frame
+        # and dense streams fall through to the per-stream path
+        pre: dict[int, tuple[np.ndarray, np.ndarray]] = {}
+        rows = [
+            i for i, (s, _) in enumerate(members)
+            if s.gating and s._prev is not None
+        ]
+        if len(rows) > 1:
+            kern = gating.host_gate_kernels(spec)
+            prevs = np.stack([
+                np.asarray(members[i][0]._prev, np.float32) for i in rows
+            ])
+            curs_in = np.stack([members[i][1] for i in rows])
+            curs, deltas = kern.step_batch(prevs, curs_in)
+            curs, deltas = np.asarray(curs), np.asarray(deltas)
+            self.stats.h2d_bytes += prevs.nbytes + curs_in.nbytes
+            self.stats.d2h_bytes += curs.nbytes + deltas.nbytes
+            pre = {i: (curs[j], deltas[j]) for j, i in enumerate(rows)}
+        for row, (session, frame) in enumerate(members):
+            frame_idx = session.frame_idx
+            block = session.step(frame, precomputed=pre.get(row))
+            window = session.last_window_mask if session.gating else None
+            kept = int(window.sum()) if window is not None else h_o * w_o
+            entry = {
+                "stream_id": session.stream_id,
+                "frame_idx": frame_idx,
+                "block_mask": block,
+                "kept": kept,
+                "total": h_o * w_o,
+            }
+            if session.per_config:
+                entry["per_config"] = {
+                    st.name: (
+                        st.last_block_mask,
+                        int(st.last_window_mask.sum()),
+                        st.last_window_mask,
+                    )
+                    for st in session._states
+                }
+            tap = self.event_taps.get(session.stream_id)
+            if tap is not None:
+                # emit this tick's address-event packet from the gate
+                # state session.step() just wrote (same changed array the
+                # gate counted — the reconciliation contract)
+                entry["events"] = tap.observe_tick(frame_idx)
+            entries.append(entry)
+            if gated:
+                keeps.append(
+                    window
+                    if window is not None
+                    else np.ones((h_o, w_o), bool)
+                )
+            self.stats.frames += 1
+            self.stats.windows_total += h_o * w_o
+            self.stats.windows_kept += kept
+        return entries, keeps
 
     def _model_head_pass(
         self, launch: dict, members: list, h_o: int, w_o: int
@@ -940,8 +980,10 @@ class StreamServer:
             if len(group) == 1 or not self.fuse_shared_heads:
                 for name, lo, hi, cfg in group:
                     sliced, prevs, keeps = gather(name, lo, hi, cfg)
+                    keep = np.stack(keeps)
+                    self.stats.h2d_bytes += keep.nbytes
                     logits, eff = handle.patched_logits(
-                        sliced, jnp.stack(prevs), np.stack(keeps),
+                        sliced, jnp.stack(prevs), keep,
                         head_params=cfg.head_params,
                     )
                     for row, (session, _) in enumerate(members):
@@ -960,11 +1002,13 @@ class StreamServer:
                 hp_stack = jax.tree_util.tree_map(
                     lambda *xs: jnp.stack(xs), *hp_rows
                 )
+                keep = np.stack(rows_k)
+                self.stats.h2d_bytes += keep.nbytes
                 logits, eff = handle.fused_patched_logits(
                     hp_stack,
                     jnp.concatenate(rows_c, axis=0),
                     jnp.stack(rows_p),
-                    np.stack(rows_k),
+                    keep,
                 )
                 self.stats.fused_head_calls += 1
                 for g, (name, lo, hi, cfg) in enumerate(group):
@@ -991,6 +1035,9 @@ class StreamServer:
                 name: np.asarray(lg)
                 for name, lg in launch.get("logits", {}).items()
             }
+            self.stats.d2h_bytes += counts.nbytes + sum(
+                lg.nbytes for lg in logits_np.values()
+            )
             detect = launch.get("detect", {})
             for row, e in enumerate(launch["entries"]):
                 per_config = e.get("per_config")
@@ -1034,30 +1081,42 @@ class StreamServer:
         device compute overlaps tick ``t+1``'s host gating/batching; results
         are realised oldest-first, preserving frame order per stream.
         """
-        inflight: collections.deque[list[dict]] = collections.deque()
+        inflight: collections.deque[tuple[int, list[dict]]] = collections.deque()
         for frames in ticks:
             # single-exit wall-clock billing: the dispatch half of the tick
             # is accumulated exactly once even when the gate/batch path
             # raises, so fps_wall never loses (or double-counts) time
             t0 = time.perf_counter()
             try:
-                with telemetry.span("serve_tick", self._span_fields):
-                    inflight.append(self._dispatch(frames))
+                tick = int(self.stats.ticks)
+                with telemetry.span("serve_tick", self._tick_fields(tick)):
+                    inflight.append((tick, self._dispatch(frames)))
                 self.stats.ticks += 1
             finally:
                 self.stats.serve_seconds += time.perf_counter() - t0
             while len(inflight) > self.depth:
-                yield self._finalize_timed(inflight.popleft())
+                yield self._finalize_timed(*inflight.popleft())
         while inflight:
-            yield self._finalize_timed(inflight.popleft())
+            yield self._finalize_timed(*inflight.popleft())
 
-    def _finalize_timed(self, launches: list[dict]) -> list[StreamFrameResult]:
-        """Realise one in-flight tick, billing its wall time exactly once
-        (``try/finally`` — a device error mid-realisation still accounts
-        the seconds already spent)."""
+    def _tick_fields(self, tick: int) -> dict | None:
+        """Span fields naming one tick: built only while a telemetry
+        session is on, so a disabled tick allocates no dict."""
+        if not telemetry.enabled():
+            return None
+        return {**self._span_fields, "tick": tick}
+
+    def _finalize_timed(
+        self, tick: int, launches: list[dict]
+    ) -> list[StreamFrameResult]:
+        """Realise one in-flight tick (the ``realise`` span, its ``tick``
+        that of the ``serve_tick`` it realises), billing its wall time
+        exactly once (``try/finally`` — a device error mid-realisation
+        still accounts the seconds already spent)."""
         t0 = time.perf_counter()
         try:
-            return self._finalize(launches)
+            with telemetry.span("realise", self._tick_fields(tick)):
+                return self._finalize(launches)
         finally:
             self.stats.serve_seconds += time.perf_counter() - t0
 

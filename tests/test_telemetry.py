@@ -10,10 +10,12 @@ from __future__ import annotations
 import json
 import types
 
+import jax
 import numpy as np
 import pytest
 
 from repro import fpca
+from repro.core import mapping
 from repro.core.mapping import FPCASpec
 from repro.fpca import telemetry
 from repro.fpca.cache import ExecutableCache
@@ -53,7 +55,7 @@ def served(tmp_path_factory):
     )
     server.add_stream("cam0", "edges")
     frames = (rng.normal(size=(12, 24, 24, 3)) * 0.1).astype(np.float32)
-    telemetry.enable(path, device_time_rate=2)
+    telemetry.enable(path)
     list(server.serve("cam0", frames[:4]))
     list(server.serve_segments("cam0", frames[4:8], segment_length=4))
     list(server.serve("cam0", frames[8:]))
@@ -96,13 +98,142 @@ def test_span_nesting_across_segments(served):
         assert s["dur_s"] >= 0
 
 
-def test_device_time_sampling(served):
-    """device_time_rate=2 blocked on every 2nd instrumented launch."""
-    samples = [e for e in served.events if e["event"] == "device_time"]
-    assert samples, "no device-time samples despite device_time_rate=2"
-    for s in samples:
-        assert s["dur_s"] >= 0
-        assert s["backend"] == "basis"
+# -- per-tick phase spans and host<->device byte counters ----------------------
+
+PHASES = ("gate", "stage", "frontend", "head")
+N_CAMS, N_TICKS = 3, 6
+
+
+def _serve_model_fleet(path=None):
+    """Three gated cameras on a model config, fresh noise every tick (so
+    every tick keeps windows): served with telemetry on when ``path`` is
+    given, off otherwise."""
+    rng = np.random.default_rng(2)
+    kernel = rng.normal(size=(4, 3, 3, 3)).astype(np.float32)
+    model = fpca.FPCAModelProgram(
+        frontend=fpca.FPCAProgram(spec=SPEC),
+        head=(fpca.DenseSpec(8, activation="relu"), fpca.DenseSpec(3)),
+    )
+    pipe = FPCAPipeline(backend="basis")
+    pipe.register("cls", model, kernel,
+                  head_params=model.init_head(jax.random.PRNGKey(0)))
+    server = StreamServer(pipe, gate=fpca.DeltaGateConfig(threshold=0.05))
+    ids = [f"cam{i}" for i in range(N_CAMS)]
+    for sid in ids:
+        server.add_stream(sid, "cls")
+    ticks = [
+        {sid: rng.uniform(0, 1, (24, 24, 3)).astype(np.float32) for sid in ids}
+        for _ in range(N_TICKS)
+    ]
+    if path is not None:
+        telemetry.enable(path)
+    out = list(server.run(ticks))
+    telemetry.disable()
+    events = telemetry.read_jsonl(path) if path is not None else []
+    return server, out, [e for e in events if e["event"] == "span"]
+
+
+def _reckoned_bytes(server) -> tuple[int, int]:
+    """Host->device and device->host bytes of N_TICKS ticks, from shapes."""
+    h_o, w_o = mapping.output_dims(SPEC)
+    eff = 4 * SPEC.eff_h * SPEC.eff_w                  # float32 effective frame
+    frame = 4 * 24 * 24 * 3                            # float32 camera frame
+    grid = 4 * (-(-SPEC.eff_h // SPEC.skip_block)) * (-(-SPEC.eff_w // SPEC.skip_block))
+    keep = h_o * w_o                                   # bool window keep grid
+    padded = 4                                         # pow-2 batch of 3
+    assert server.sessions["cam0"]._prev.nbytes == eff
+    first_h2d, first_d2h = N_CAMS * frame, N_CAMS * eff          # solo eff()
+    gate_h2d = (N_TICKS - 1) * N_CAMS * (eff + frame)            # step_batch
+    gate_d2h = (N_TICKS - 1) * N_CAMS * (eff + grid)
+    tick_h2d = N_TICKS * (N_CAMS * frame + padded * keep + N_CAMS * keep)
+    tick_d2h = N_TICKS * N_CAMS * (4 * h_o * w_o * 4 + 4 * 3)   # counts, logits
+    return (first_h2d + gate_h2d + tick_h2d, first_d2h + gate_d2h + tick_d2h)
+
+
+@pytest.fixture(scope="module")
+def fleet_spans(tmp_path_factory):
+    return _serve_model_fleet(tmp_path_factory.mktemp("phases") / "spans.jsonl")
+
+
+def test_phase_spans_nest_under_serve_tick(fleet_spans):
+    """gate, stage, frontend and head run once per tick, in that order,
+    inside their serve_tick; every record carries t0_ns."""
+    _, _, spans = fleet_spans
+    ticks = [s for s in spans if s["span"] == "serve_tick"]
+    assert [s["tick"] for s in ticks] == list(range(N_TICKS))
+    for s in spans:
+        assert isinstance(s["t0_ns"], int) and s["dur_s"] >= 0
+    for tick in ticks:
+        lo = tick["t0_ns"]
+        hi = lo + int(tick["dur_s"] * 1e9) + 1
+        inside = [s for s in spans if s["span"] in PHASES and lo <= s["t0_ns"] <= hi]
+        assert [s["span"] for s in inside] == list(PHASES)
+        for s in inside:
+            assert s["parent"] == "serve_tick" and s["depth"] == 1
+            assert s["t0_ns"] + int(s["dur_s"] * 1e9) <= hi
+
+
+def test_realise_follows_its_tick_at_depth_two(fleet_spans):
+    """One realise per tick with the tick's id, starting after that tick's
+    serve_tick ends and after tick + depth was dispatched (the depth-2
+    overlap telemetry must not change)."""
+    server, out, spans = fleet_spans
+    ends = {s["tick"]: s["t0_ns"] + int(s["dur_s"] * 1e9)
+            for s in spans if s["span"] == "serve_tick"}
+    realised = [s for s in spans if s["span"] == "realise"]
+    assert [s["tick"] for s in realised] == list(range(N_TICKS))
+    for r in realised:
+        assert r["parent"] is None
+        assert r["t0_ns"] >= ends[r["tick"]]
+        later = r["tick"] + server.depth
+        if later in ends:
+            assert r["t0_ns"] >= ends[later]
+    assert [res[0].frame_idx for res in out] == list(range(N_TICKS))
+
+
+@pytest.mark.parametrize("on", [True, False], ids=["enabled", "disabled"])
+def test_byte_counters_match_shapes(on, fleet_spans):
+    """h2d_bytes / d2h_bytes are the nbytes reckoned from the shapes, with
+    telemetry on or off: disabled, every span is the shared null object and
+    the counters still count."""
+    if on:
+        server = fleet_spans[0]
+    else:
+        server = _serve_model_fleet()[0]
+        for name in PHASES + ("serve_tick", "realise"):
+            assert telemetry.span(name) is telemetry._NULL_SPAN
+    assert (server.stats.h2d_bytes, server.stats.d2h_bytes) == _reckoned_bytes(server)
+    assert_reconciled(server.pipeline, server)
+
+
+@pytest.mark.parametrize("profile", [True, False])
+def test_span_enters_profiler_annotation(profile, monkeypatch):
+    """With profile=True a span runs inside TraceAnnotation("fpca:<name>");
+    with profile=False it creates none."""
+    entered = []
+
+    class Annotation:
+        def __init__(self, name, **kw):
+            assert not kw
+            self.name = name
+
+        def __enter__(self):
+            entered.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            entered.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    telemetry.enable(None, profile=profile)
+    with telemetry.span("gate", {"tick": 3}):
+        with telemetry.span("stage"):
+            pass
+    telemetry.disable()
+    if profile:
+        assert entered == [("enter", "fpca:gate"), ("enter", "fpca:stage"),
+                           ("exit", "fpca:stage"), ("exit", "fpca:gate")]
+    else:
+        assert entered == []
 
 
 # -- reconciliation / single-sourcing ----------------------------------------
