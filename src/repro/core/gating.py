@@ -191,12 +191,19 @@ class HostGateKernels(NamedTuple):
     one per stream, which is what keeps the per-tick host cost flat as the
     fleet grows (the weak-scaling lane of ``benchmarks/fleet_bench.py``).
     It compiles once per fleet size; the per-row math is the identical
-    trace, so batched and solo gate decisions agree bit for bit."""
+    trace, so batched and solo gate decisions agree bit for bit.
+
+    The new effective frame a step returns is meant to stay on the device
+    as the next step's ``prev_eff``: the caller reads back only the block
+    |Δ| grid the thresholds compare.  The ``*_signed`` variants also return
+    the signed block-mean change, the polarity an event tap needs."""
 
     eff: Callable        # frame -> effective frame
     delta: Callable      # (prev_eff, cur_eff) -> block |Δ| grid
     step: Callable       # (prev_eff, frame) -> (cur_eff, block |Δ| grid)
     step_batch: Callable  # (n, ...) stacked twin of ``step``
+    step_signed: Callable  # (prev_eff, frame) -> (cur_eff, |Δ|, signed Δ)
+    step_batch_signed: Callable  # (n, ...) stacked twin of ``step_signed``
 
 
 @functools.lru_cache(maxsize=None)
@@ -208,6 +215,12 @@ def host_gate_kernels(spec: FPCASpec) -> HostGateKernels:
         cur = effective_frame(frame, spec)
         return cur, block_delta(prev_eff, cur, spec)
 
+    def _step_signed(prev_eff, frame):
+        cur, delta_blocks = _step(prev_eff, frame)
+        signed = block_reduce_mean(cur - prev_eff, spec.skip_block)
+        return cur, delta_blocks, signed
+
     return HostGateKernels(
-        eff, delta, jax.jit(_step), jax.jit(jax.vmap(_step))
+        eff, delta, jax.jit(_step), jax.jit(jax.vmap(_step)),
+        jax.jit(_step_signed), jax.jit(jax.vmap(_step_signed)),
     )

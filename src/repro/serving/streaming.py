@@ -237,6 +237,39 @@ class _GateState:
         return keep
 
 
+class _PrevStack:
+    """The previous effective frames of one group's gated streams, stacked
+    on the device as ``(n, eff_h, eff_w)``, row ``j`` for ``sessions[j]``.
+    A stream's previous frame is that row while its session's
+    ``_prev_src`` is ``(stack, j)``; each tick's batched gate step replaces
+    ``prev`` with the new stack."""
+
+    __slots__ = ("sessions", "prev")
+
+    def __init__(self, sessions: list, prev: jax.Array):
+        self.sessions = sessions
+        self.prev = prev
+
+    def holds(self, sessions: list) -> bool:
+        """True when ``sessions``, in this order, are exactly the streams
+        whose previous frames are this stack's rows."""
+        return len(sessions) == len(self.sessions) and all(
+            s is t and isinstance(s._prev_src, tuple)
+            and s._prev_src[0] is self and s._prev_src[1] == j
+            for j, (s, t) in enumerate(zip(sessions, self.sessions))
+        )
+
+    def release(self, keep: list) -> None:
+        """Give each stream still reading a row of this stack, other than
+        ``keep``, its own copy of the row, so no stream pins a stack its
+        group has left behind."""
+        kept = {id(s) for s in keep}
+        for j, s in enumerate(self.sessions):
+            src = s._prev_src
+            if id(s) not in kept and isinstance(src, tuple) and src[0] is self:
+                s._prev_src = self.prev[j]
+
+
 class StreamSession:
     """Per-stream state: previous frame, block ages, programmed config(s).
 
@@ -254,6 +287,10 @@ class StreamSession:
 
     ``stats`` (the owning server's :class:`StreamStats`) is billed the
     host↔device bytes of the gate dispatches this session makes itself.
+
+    The previous effective frame stays on the device between ticks (a row
+    of its group's stacked array under :class:`StreamServer`, its own
+    array when stepped alone); :attr:`_prev` reads it back on demand.
     """
 
     def __init__(
@@ -278,7 +315,10 @@ class StreamSession:
             controller, Mapping
         )
         self.frame_idx = 0
-        self._prev: np.ndarray | None = None
+        # where the previous effective frame lives: None before the first
+        # frame, a host array (seeded by absorb_segment), this session's own
+        # device array (stepped alone) or ``(stack, row)`` of a _PrevStack
+        self._prev_src: Any = None
         bh = math.ceil(spec.eff_h / spec.skip_block)
         bw = math.ceil(spec.eff_w / spec.skip_block)
         self.last_window_mask: np.ndarray | None = None
@@ -289,9 +329,10 @@ class StreamSession:
         # device-resident carry threaded between compiled segment launches
         # (None until the stream first serves a segment)
         self._segment_state: Any | None = None
-        # set by an attached EventTap: step() then retains the SIGNED block
-        # mean delta (the gate only needs |Δ|) so event polarity can be read
-        # after the previous frame is overwritten
+        # set by an attached EventTap: the gate dispatch then also computes
+        # the SIGNED block-mean delta (the gate only needs |Δ|) and step()
+        # retains it, so event polarity can be read after the previous frame
+        # is overwritten
         self.want_events = False
         self._last_signed: np.ndarray | None = None
 
@@ -375,10 +416,27 @@ class StreamSession:
         """This config's gate state (shared state unless per-config)."""
         return self._by_name.get(config)
 
+    @property
+    def _prev(self) -> np.ndarray | None:
+        """The previous effective frame, read back to the host (None before
+        the first frame)."""
+        prev = self._prev_value()
+        return None if prev is None else np.asarray(prev, np.float32)
+
+    def _prev_value(self) -> Any:
+        """The previous effective frame where it lives: a device array (a
+        row of a group's stack is sliced out), a host array, or None."""
+        src = self._prev_src
+        if isinstance(src, tuple):
+            stack, row = src
+            return stack.prev[row]
+        return src
+
     def step(
         self,
-        frame: np.ndarray,
-        precomputed: tuple[np.ndarray, np.ndarray] | None = None,
+        frame: Any,
+        precomputed: tuple[Any, np.ndarray | None, np.ndarray | None]
+        | None = None,
     ) -> np.ndarray | None:
         """Advance one frame; returns the block keep mask (None = dense).
 
@@ -394,47 +452,28 @@ class StreamSession:
         fused call must execute; each config's own decision is on its
         :meth:`state_for` entry.
 
-        ``precomputed`` is this tick's ``(effective frame, block |Δ| grid)``
-        when the server already computed it in a fleet-batched gate dispatch
+        ``precomputed`` is this tick's ``(effective frame, block |Δ| grid,
+        signed block-mean Δ)`` when the server already gated the stream in
+        a fleet-batched dispatch on the device
         (:func:`repro.core.gating.HostGateKernels.step_batch` — bit-identical
-        to the solo kernel); the per-config threshold comparisons and age
-        bookkeeping still run here, per stream.
+        to the solo kernel): the effective frame is where it stays on the
+        device, the ``(stack, row)`` of its group's :class:`_PrevStack`;
+        the grids are host arrays (None on the first frame; the signed grid
+        is None unless :attr:`want_events`).  The per-config threshold comparisons and age
+        bookkeeping still run here, per stream.  Without it the stream is
+        gated alone: the frame (a host or device array) goes to the device,
+        the effective frame stays there, and only the grids come back.
         """
         if not self.gating:
             self.frame_idx += 1
             return None
-        delta_blocks = None
         if precomputed is not None:
-            cur = np.asarray(precomputed[0])
-            delta_blocks = np.asarray(precomputed[1])
-        elif self._prev is None:
-            kernels = gating.host_gate_kernels(self.spec)
-            frame = np.asarray(frame, np.float32)
-            cur = np.asarray(kernels.eff(frame))
-            self._bill(frame.nbytes, cur.nbytes)
+            cur, delta_blocks, signed = precomputed
         else:
-            # ONE fused dispatch per tick (effective frame + block delta):
-            # the gate result is needed synchronously to build this tick's
-            # window mask, so per-call overhead sits on the serving hot loop
-            kernels = gating.host_gate_kernels(self.spec)
-            prev = np.asarray(self._prev, np.float32)
-            frame = np.asarray(frame, np.float32)
-            cur_d, delta_d = kernels.step(prev, frame)
-            cur = np.asarray(cur_d)
-            delta_blocks = np.asarray(delta_d)
-            self._bill(prev.nbytes + frame.nbytes,
-                       cur.nbytes + delta_blocks.nbytes)
+            cur, delta_blocks, signed = self._gate_alone(frame)
         if self.want_events:
-            # polarity source for the event tap: signed block-mean change,
-            # captured before ``_prev`` is overwritten below
-            self._last_signed = (
-                None
-                if delta_blocks is None or self._prev is None
-                else _block_reduce_mean(
-                    cur - np.asarray(self._prev, np.float32),
-                    self.spec.skip_block,
-                )
-            )
+            # polarity source for the event tap: signed block-mean change
+            self._last_signed = signed
         union_keep: np.ndarray | None = None
         union_window: np.ndarray | None = None
         for st in self._states:
@@ -444,15 +483,48 @@ class StreamSession:
             union_window = (
                 window if union_window is None else union_window | window
             )
-        self._prev = cur
+        self._prev_src = cur
         self.frame_idx += 1
         self.last_window_mask = union_window
         return union_keep
+
+    def _gate_alone(self, frame: Any) -> tuple:
+        """One gate dispatch for this stream alone (ONE fused kernel per
+        tick: the gate result is needed synchronously to build this tick's
+        window mask).  Returns ``(effective frame on the device, |Δ| grid,
+        signed grid)``, the grids None on the first frame."""
+        kernels = gating.host_gate_kernels(self.spec)
+        if not isinstance(frame, jax.Array):
+            frame = np.asarray(frame, np.float32)
+            self._bill(frame.nbytes, 0)
+        prev = self._prev_value()
+        self._count(resident=isinstance(self._prev_src, jax.Array))
+        if prev is None:
+            return kernels.eff(frame), None, None
+        if isinstance(prev, np.ndarray):
+            self._bill(prev.nbytes, 0)
+        if self.want_events:
+            cur, delta_d, signed_d = kernels.step_signed(prev, frame)
+            signed = np.asarray(signed_d)
+        else:
+            cur, delta_d = kernels.step(prev, frame)
+            signed = None
+        delta_blocks = np.asarray(delta_d)
+        self._bill(0, delta_blocks.nbytes + (
+            0 if signed is None else signed.nbytes))
+        return cur, delta_blocks, signed
 
     def _bill(self, h2d: int, d2h: int) -> None:
         if self.stats is not None:
             self.stats.h2d_bytes += h2d
             self.stats.d2h_bytes += d2h
+
+    def _count(self, resident: bool) -> None:
+        if self.stats is not None:
+            if resident:
+                self.stats.gate_rows_resident += 1
+            else:
+                self.stats.gate_rows_restacked += 1
 
     def absorb_segment(self, seg) -> None:
         """Fold one finished device-compiled segment into this session.
@@ -493,7 +565,7 @@ class StreamSession:
             st.last_window_mask = window
             self.last_window_mask = window
         st.age = np.asarray(seg.state.age, np.int64)
-        self._prev = np.asarray(seg.state.prev_eff, np.float32)
+        self._prev_src = np.asarray(seg.state.prev_eff, np.float32)
         self.frame_idx = int(seg.state.frame_idx)
         if st.controller is not None and ticks:
             obs = None
@@ -595,6 +667,12 @@ class StreamStats(telemetry.StatsView):
     across the host↔device boundary: ``nbytes`` of every host array that
     enters a device call (gate inputs, frames, keep grids) and of every
     device array it realises on the host (gate results, counts, logits).
+    ``gate_rows_resident`` / ``gate_rows_restacked`` split the gated
+    stream-ticks by where the previous effective frame came from: the
+    device-resident stack of the stream's group as the last tick left it
+    (or the stream's own device array, gated alone), or state that had to
+    be rebuilt first (a first frame, a change of the group's members or
+    their order, a state replaced by a segment).
 
     The server deliberately does NOT parent-chain into the pipeline's
     stats: it is a scoped observer of a *shared* pipeline (other callers
@@ -617,6 +695,8 @@ class StreamStats(telemetry.StatsView):
         "serve_seconds",
         "h2d_bytes",
         "d2h_bytes",
+        "gate_rows_resident",
+        "gate_rows_restacked",
     )
 
 
@@ -803,8 +883,10 @@ class StreamServer:
         """Host side of one tick: gate every stream, fan streams into one
         batch per configuration group, dispatch without blocking.
 
-        Each group's work runs under the telemetry spans ``gate``,
-        ``stage``, ``frontend`` and ``head``, in that order."""
+        Each group's work runs under the telemetry spans ``stage``,
+        ``gate``, ``frontend`` and ``head``, in that order: the group's
+        frames go to the device once, and the gate and the frontend both
+        read that copy."""
         per_group: dict[tuple[str, ...], list[tuple[StreamSession, np.ndarray]]] = {}
         for stream_id, frame in frames.items():
             session = self.sessions.get(stream_id)
@@ -825,13 +907,14 @@ class StreamServer:
             spec = members[0][0].spec
             h_o, w_o = mapping.output_dims(spec)
             gated = any(session.gating for session, _ in members)
-            with telemetry.span("gate"):
-                entries, keeps = self._gate_group(members, spec, h_o, w_o, gated)
             with telemetry.span("stage"):
                 host_images = np.stack([frame for _, frame in members])
-                keep = np.stack(keeps) if gated else None
                 images = jnp.asarray(host_images)
                 self.stats.h2d_bytes += host_images.nbytes
+            with telemetry.span("gate"):
+                entries, keep = self._gate_group(
+                    members, images, spec, h_o, w_o, gated
+                )
             with telemetry.span("frontend"):
                 counts = self.pipeline.run_config_batch(
                     configs[0] if len(configs) == 1 else list(configs),
@@ -854,34 +937,15 @@ class StreamServer:
         return launches
 
     def _gate_group(
-        self, members: list, spec: mapping.FPCASpec, h_o: int, w_o: int,
-        gated: bool,
-    ) -> tuple[list[dict], list[np.ndarray]]:
-        """Gate every stream of one configuration group; returns the
-        group's result entries and (``gated``) its window keep grids."""
+        self, members: list, images: jax.Array, spec: mapping.FPCASpec,
+        h_o: int, w_o: int, gated: bool,
+    ) -> tuple[list[dict], np.ndarray | None]:
+        """Gate every stream of one configuration group on its staged
+        device frames ``images``; returns the group's result entries and
+        (``gated``) its stacked window keep grids."""
         entries = []
         keeps = []
-        # fleet-batched host gating: every warmed-up gated stream of the
-        # group computes its effective frame + block |Δ| grid in ONE
-        # vmapped dispatch (bit-identical to the solo kernel), so the
-        # per-tick host cost stays flat as the fleet grows; first-frame
-        # and dense streams fall through to the per-stream path
-        pre: dict[int, tuple[np.ndarray, np.ndarray]] = {}
-        rows = [
-            i for i, (s, _) in enumerate(members)
-            if s.gating and s._prev is not None
-        ]
-        if len(rows) > 1:
-            kern = gating.host_gate_kernels(spec)
-            prevs = np.stack([
-                np.asarray(members[i][0]._prev, np.float32) for i in rows
-            ])
-            curs_in = np.stack([members[i][1] for i in rows])
-            curs, deltas = kern.step_batch(prevs, curs_in)
-            curs, deltas = np.asarray(curs), np.asarray(deltas)
-            self.stats.h2d_bytes += prevs.nbytes + curs_in.nbytes
-            self.stats.d2h_bytes += curs.nbytes + deltas.nbytes
-            pre = {i: (curs[j], deltas[j]) for j, i in enumerate(rows)}
+        pre = self._gate_batch(members, images, spec) if gated else {}
         for row, (session, frame) in enumerate(members):
             frame_idx = session.frame_idx
             block = session.step(frame, precomputed=pre.get(row))
@@ -919,7 +983,74 @@ class StreamServer:
             self.stats.frames += 1
             self.stats.windows_total += h_o * w_o
             self.stats.windows_kept += kept
-        return entries, keeps
+        return entries, np.stack(keeps) if gated else None
+
+    def _gate_batch(
+        self, members: list, images: jax.Array, spec: mapping.FPCASpec
+    ) -> dict[int, tuple]:
+        """Fleet-batched gate of a group's gated streams: ONE vmapped
+        dispatch (bit-identical to the solo kernel) on the staged frames
+        and the group's device-resident previous effective frames, so
+        pixels never pass through the host and the per-tick host cost
+        stays flat as the fleet grows.  Only the block |Δ| grid (and, when
+        a member has an event tap, the signed grid) comes back.
+
+        The previous frames are the stack the last tick left when the same
+        streams gate in the same order; otherwise (a first frame, a stream
+        joined or left, a segment replaced a state) the stack is rebuilt
+        from each stream's own source.  Returns ``row -> precomputed`` for
+        :meth:`StreamSession.step`."""
+        rows = [i for i, (s, _) in enumerate(members) if s.gating]
+        sessions = [members[i][0] for i in rows]
+        n = len(rows)
+        srcs = [s._prev_src for s in sessions]
+        stack = srcs[0][0] if isinstance(srcs[0], tuple) else None
+        if stack is not None and stack.holds(sessions):
+            self.stats.gate_rows_resident += n
+        else:
+            prev = self._restack(sessions, spec)
+            for old in {src[0] for src in srcs if isinstance(src, tuple)}:
+                old.release(sessions)
+            stack = _PrevStack(sessions, prev)
+            self.stats.gate_rows_restacked += n
+        frames = images if n == len(members) else images[np.asarray(rows)]
+        kernels = gating.host_gate_kernels(spec)
+        if any(s.want_events for s in sessions):
+            stack.prev, delta_d, signed_d = kernels.step_batch_signed(
+                stack.prev, frames
+            )
+            signed = np.asarray(signed_d)
+            self.stats.d2h_bytes += signed.nbytes
+        else:
+            stack.prev, delta_d = kernels.step_batch(stack.prev, frames)
+            signed = None
+        deltas = np.asarray(delta_d)
+        self.stats.d2h_bytes += deltas.nbytes
+        # a stream's first frame has no delta: its grid row (against the
+        # zero placeholder) is dropped
+        return {
+            i: (
+                (stack, j),
+                None if srcs[j] is None else deltas[j],
+                None if srcs[j] is None or signed is None else signed[j],
+            )
+            for j, i in enumerate(rows)
+        }
+
+    def _restack(
+        self, sessions: list[StreamSession], spec: mapping.FPCASpec
+    ) -> jax.Array:
+        """Stack the sessions' previous effective frames on the device, a
+        zero placeholder for a stream with none yet; host arrays (a state
+        a segment replaced) are copied up."""
+        prevs = [s._prev_value() for s in sessions]
+        if all(p is None for p in prevs):
+            return jnp.zeros((len(prevs), spec.eff_h, spec.eff_w), jnp.float32)
+        zero = jnp.zeros((spec.eff_h, spec.eff_w), jnp.float32)
+        self.stats.h2d_bytes += sum(
+            p.nbytes for p in prevs if isinstance(p, np.ndarray)
+        )
+        return jnp.stack([zero if p is None else p for p in prevs])
 
     def _model_head_pass(
         self, launch: dict, members: list, h_o: int, w_o: int

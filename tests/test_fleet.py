@@ -382,7 +382,7 @@ def test_sharded_fleet_serving_matches_unsharded():
     # the compiled handles really shard over every (virtual) device...
     handles = list(pipe_m._handles.values())
     assert handles and all(h.data_parallelism == ndev for h in handles)
-    # ...while gate state stays host-local per stream
+    # ...while each stream's gate state stays readable on the host
     for session in server_m.sessions.values():
         assert isinstance(session._prev, np.ndarray)
     assert_reconciled(pipe_m, server_m)

@@ -699,3 +699,33 @@ def test_host_gate_step_batch_matches_solo_bitwise():
             np.testing.assert_array_equal(
                 np.asarray(deltas)[i], np.asarray(delta_i)
             )
+
+
+def test_host_gate_signed_step_matches_step_bitwise():
+    """The event-tap variants return the same effective frame and |Δ| grid
+    bits as the plain steps (an event tap must not move a gate decision),
+    batched rows equal solo ones, and the signed grid is the block mean
+    of the signed change."""
+    spec = _spec()
+    kernels = gating.host_gate_kernels(spec)
+    rng = np.random.default_rng(2)
+    n = 4
+    prevs = rng.uniform(0, 1, (n, spec.eff_h, spec.eff_w)).astype(np.float32)
+    frames = rng.uniform(0, 1, (n, H, W, 3)).astype(np.float32)
+    curs, deltas = kernels.step_batch(prevs, frames)
+    curs_s, deltas_s, signed = kernels.step_batch_signed(prevs, frames)
+    np.testing.assert_array_equal(np.asarray(curs_s), np.asarray(curs))
+    np.testing.assert_array_equal(np.asarray(deltas_s), np.asarray(deltas))
+    for i in range(n):
+        cur_i, delta_i, signed_i = kernels.step_signed(prevs[i], frames[i])
+        np.testing.assert_array_equal(np.asarray(cur_i), np.asarray(curs)[i])
+        np.testing.assert_array_equal(np.asarray(delta_i), np.asarray(deltas)[i])
+        np.testing.assert_array_equal(
+            np.asarray(signed_i), np.asarray(signed)[i]
+        )
+        np.testing.assert_allclose(
+            np.asarray(signed_i),
+            np.asarray(gating.block_reduce_mean(
+                np.asarray(cur_i) - prevs[i], spec.skip_block)),
+            rtol=0, atol=1e-6,
+        )

@@ -100,7 +100,7 @@ def test_block_delta_mask_shape_and_threshold_monotone(
     assert not block_delta_mask(a, a, spec, threshold).any()
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(
     hysteresis=st.integers(0, 3),
     keyframe_interval=st.sampled_from([0, 3, 5]),

@@ -14,16 +14,20 @@ Contracts pinned here:
   for many cameras) matches looped single-stream serving bit-for-bit.
 * **Cross-config batching** — configs sharing a compile signature merge into
   one channel-stacked call with unchanged per-request results.
+* **Device-resident gate** — with the frames copied up once a tick and the
+  previous effective frames kept on the device, gate decisions, counts,
+  logits and event packets equal a host-roundtrip gate's bit for bit.
 """
 
 from __future__ import annotations
 
 import json
 
+import jax
 import numpy as np
 import pytest
 
-from repro.core import analysis
+from repro.core import analysis, gating
 from repro.core.fpca_sim import fpca_forward
 from repro.core.mapping import FPCASpec, active_window_mask, output_dims
 from repro.data.pipeline import SyntheticMovingObject
@@ -35,6 +39,7 @@ from repro.serving.streaming import (
     GateControllerConfig,
     StreamServer,
     StreamSession,
+    _block_reduce_mean,
     block_delta_mask,
 )
 
@@ -885,3 +890,250 @@ def test_compiled_stream_matches_server_solo_dense(bucket_model):
         assert s.block_mask is None and h.block_mask is None
         assert s.kept_windows == h.kept_windows == s.total_windows
         np.testing.assert_array_equal(s.counts, h.counts)
+
+
+# ---------------------------------------------------------------------------
+# device-resident gate: the gate's pixels stay on the device
+# ---------------------------------------------------------------------------
+
+
+class _HostRoundTripServer(StreamServer):
+    """The reference gate with its pixels on the host: each stream's
+    previous effective frame is a host array, stacked with the frames and
+    copied up for one batched step (the solo kernel for a lone stream),
+    and the new effective frames are read back; the event polarity is the
+    numpy block mean of the host difference."""
+
+    def _gate_batch(self, members, images, spec):
+        kernels = gating.host_gate_kernels(spec)
+        rows = [i for i, (s, _) in enumerate(members)
+                if s.gating and s._prev is not None]
+        if len(rows) > 1:
+            curs, deltas = kernels.step_batch(
+                np.stack([members[i][0]._prev for i in rows]),
+                np.stack([members[i][1] for i in rows]),
+            )
+            pre = {i: (curs[j], deltas[j]) for j, i in enumerate(rows)}
+        else:
+            pre = {i: kernels.step(members[i][0]._prev, members[i][1])
+                   for i in rows}
+        out = {}
+        for i, (session, frame) in enumerate(members):
+            if not session.gating:
+                continue
+            if i not in pre:
+                out[i] = (np.asarray(kernels.eff(frame)), None, None)
+                continue
+            cur, delta = (np.asarray(a) for a in pre[i])
+            signed = (
+                _block_reduce_mean(cur - session._prev, spec.skip_block)
+                if session.want_events else None
+            )
+            out[i] = (cur, delta, signed)
+        return out
+
+
+def _model_program(spec):
+    import repro.fpca as fpca
+
+    return fpca.FPCAModelProgram(
+        frontend=fpca.FPCAProgram(spec=spec),
+        head=(fpca.DenseSpec(8, activation="relu"), fpca.DenseSpec(3)),
+    )
+
+
+@pytest.fixture(scope="module")
+def resident_pipe(bucket_model):
+    """A model config ``cls`` and two frontend configs ``A`` / ``B`` that
+    share its spec (multi-config fan-out), on one executable cache."""
+    spec = _spec()
+    rng = np.random.default_rng(71)
+    model = _model_program(spec)
+    pipe = FPCAPipeline(bucket_model, backend="basis")
+    pipe.register("cls", model,
+                  (rng.normal(size=(4, 5, 5, 3)) * 0.2).astype(np.float32),
+                  head_params=model.init_head(jax.random.PRNGKey(7)))
+    pipe.register("A", spec, (rng.normal(size=(4, 5, 5, 3)) * 0.2).astype(np.float32))
+    pipe.register("B", spec, (rng.normal(size=(6, 5, 5, 3)) * 0.2).astype(np.float32))
+    return pipe
+
+
+RESIDENT_GATE = DeltaGateConfig(threshold=0.02, hysteresis=1, keyframe_interval=5)
+
+
+def _resident_scenario(name):
+    """``(streams, steps)``: ``add_stream`` arguments, then runs of ticks
+    (``("ticks", [present stream ids, ...])``, each stream's next frame of
+    its own moving scene) and compiled segments (``("segment", sid, k)``)."""
+    one = {}
+    if name == "churn":            # streams join, leave, come back, reorder
+        streams = [("c0", "cls", one), ("c1", "cls", one), ("c2", "cls", one)]
+        steps = [("ticks", [("c0", "c1"), ("c0", "c1"), ("c0", "c1", "c2"),
+                            ("c0", "c1", "c2"), ("c0", "c2"), ("c2", "c0"),
+                            ("c2", "c0"), ("c0", "c1", "c2")])]
+    elif name == "alone":          # one-stream groups
+        streams = [("c0", "cls", one), ("c1", "A", one)]
+        steps = [("ticks", [("c0", "c1")] * 6)]
+    elif name == "per_config":     # per-config gates beside a shared gate
+        gates = {"A": DeltaGateConfig(threshold=0.01, hysteresis=1,
+                                      keyframe_interval=4),
+                 "B": DeltaGateConfig(threshold=0.08, hysteresis=0,
+                                      keyframe_interval=0)}
+        streams = [("c0", ("A", "B"), {"gate": gates}), ("c1", ("A", "B"), one)]
+        steps = [("ticks", [("c0", "c1")] * 7)]
+    elif name == "events":         # an event tap in a batched group
+        streams = [("c0", "cls", {"events": True}), ("c1", "cls", one)]
+        steps = [("ticks", [("c0", "c1")] * 3 + [("c0",)] + [("c0", "c1")] * 3)]
+    elif name == "interleave":     # per-tick -> segment -> per-tick
+        streams = [("c0", "cls", {"events": True}), ("c1", "cls", one)]
+        steps = [("ticks", [("c0", "c1")] * 3), ("segment", "c0", 4),
+                 ("ticks", [("c0", "c1")] * 3), ("segment", "c0", 2),
+                 ("ticks", [("c0", "c1")] * 2)]
+    else:
+        raise ValueError(name)
+    return streams, steps
+
+
+def _serve_resident(server_cls, pipe, name):
+    streams, steps = _resident_scenario(name)
+    server = server_cls(pipe, RESIDENT_GATE)
+    cams = {}
+    for i, (sid, configs, kw) in enumerate(streams):
+        server.add_stream(sid, configs, **kw)
+        cams[sid] = SyntheticMovingObject((H, W), seed=60 + i, radius=4.0)
+    sent = {sid: 0 for sid in cams}
+
+    def frame(sid):
+        sent[sid] += 1
+        return cams[sid].frame_at(sent[sid] - 1)
+
+    out = []
+    for step in steps:
+        if step[0] == "ticks":
+            ticks = [{sid: frame(sid) for sid in present} for present in step[1]]
+            out += [r for results in server.run(ticks) for r in results]
+        else:
+            _, sid, k = step
+            out += server.run_segment(sid, np.stack([frame(sid) for _ in range(k)]))
+    return server, out
+
+
+RESIDENT_SCENARIOS = ["churn", "alone", "per_config", "events", "interleave"]
+
+
+@pytest.mark.parametrize("name", RESIDENT_SCENARIOS)
+def test_device_resident_gate_matches_host_roundtrip(resident_pipe, name):
+    """Gate decisions, window keep counts, counts, logits and event packets
+    of a multi-tick fleet run equal the host-roundtrip gate's bit for bit,
+    through stream churn, lone streams, per-config gates, event taps and
+    the per-tick / segment interleave."""
+    from repro.serving.observe import assert_reconciled
+
+    server, got = _serve_resident(StreamServer, resident_pipe, name)
+    _, want = _serve_resident(_HostRoundTripServer, resident_pipe, name)
+    assert len(got) == len(want) > 0
+    gated = False
+    for a, b in zip(got, want):
+        assert (a.stream_id, a.frame_idx, a.config) == (
+            b.stream_id, b.frame_idx, b.config)
+        assert a.kept_windows == b.kept_windows
+        np.testing.assert_array_equal(a.block_mask, b.block_mask)
+        np.testing.assert_array_equal(a.counts, b.counts)
+        if b.logits is None:
+            assert a.logits is None
+        else:
+            np.testing.assert_array_equal(a.logits, b.logits)
+        if b.events is None:
+            assert a.events is None
+        else:
+            assert a.events.coords.tolist() == b.events.coords.tolist()
+            assert a.events.polarity.tolist() == b.events.polarity.tolist()
+        gated |= 0 < a.kept_windows < a.total_windows
+    assert gated                            # the gate actually gated
+    s = server.stats
+    assert s.gate_rows_resident > 0
+    assert s.gate_rows_resident + s.gate_rows_restacked == s.frames - s.segment_ticks
+    assert_reconciled(resident_pipe, server)
+    if name in ("events", "interleave"):
+        assert server.event_taps["c0"].stats.events > 0
+
+
+def test_gate_row_counters_count_resident_and_restacked(resident_pipe):
+    """Every gated stream-tick is either gated from the stack its group's
+    last tick left on the device or restacked: a first frame, a stream
+    joining or leaving, a reorder, a state a segment replaced."""
+    server = StreamServer(resident_pipe, RESIDENT_GATE)
+    for sid in ("c0", "c1", "c2"):
+        server.add_stream(sid, "cls")
+    cam = SyntheticMovingObject((H, W), seed=5, radius=4.0)
+    frames = iter(cam.frame_at(t) for t in range(64))
+
+    def run(*ticks):
+        list(server.run([{sid: next(frames) for sid in t} for t in ticks]))
+        return server.stats.gate_rows_resident, server.stats.gate_rows_restacked
+
+    assert run(("c0", "c1")) == (0, 2)                    # first frames
+    assert run(("c0", "c1"), ("c0", "c1")) == (4, 2)
+    assert run(("c0", "c1", "c2")) == (4, 5)              # c2 joins
+    assert run(("c0", "c1", "c2")) == (7, 5)
+    assert run(("c0", "c2")) == (7, 7)                    # c1 leaves
+    assert run(("c2", "c0")) == (7, 9)                    # reorder
+    assert run(("c2", "c0")) == (9, 9)
+    server.run_segment("c2", np.stack([next(frames) for _ in range(2)]))
+    assert run(("c2", "c0")) == (9, 11)                   # segment replaced c2
+    assert run(("c2", "c0")) == (11, 11)
+    # a stream stepped alone keeps its own device array
+    session = StreamSession("solo", "cls", _spec(), RESIDENT_GATE,
+                            stats=server.stats)
+    for _ in range(3):
+        session.step(next(frames))
+    assert (server.stats.gate_rows_resident,
+            server.stats.gate_rows_restacked) == (13, 12)
+    assert isinstance(session._prev, np.ndarray)
+
+
+def test_steady_h2d_bytes_are_frame_plus_two_keep_grids(resident_pipe):
+    """Once every row is resident, a frame costs its own bytes up plus the
+    frontend's and the head's keep grids; only the |Δ| grid of the gate
+    comes back beside the counts and logits."""
+    spec = _spec()
+    h_o, w_o = output_dims(spec)
+    server = StreamServer(resident_pipe, RESIDENT_GATE)
+    ids = ("c0", "c1", "c2", "c3")           # a pow-2 batch: no padding
+    for sid in ids:
+        server.add_stream(sid, "cls")
+    rng = np.random.default_rng(3)
+    ticks = [{sid: rng.uniform(0, 1, (H, W, 3)).astype(np.float32) for sid in ids}
+             for _ in range(5)]
+    list(server.run(ticks[:2]))
+    s = server.stats
+    h2d, d2h, frames = s.h2d_bytes, s.d2h_bytes, s.frames
+    resident = s.gate_rows_resident
+    list(server.run(ticks[2:]))
+    n = s.frames - frames
+    assert s.gate_rows_resident - resident == n == 3 * len(ids)
+    frame, keep = 4 * H * W * 3, h_o * w_o
+    grid = 4 * int(np.prod(gating.block_grid(spec)))
+    assert (s.h2d_bytes - h2d) / n == frame + 2 * keep
+    assert (s.d2h_bytes - d2h) / n == grid + 4 * h_o * w_o * 4 + 4 * 3
+
+
+def test_streams_left_behind_keep_their_own_row(resident_pipe):
+    """A stream that sits out a tick keeps its previous frame as its own
+    device row, not the whole stack its group left behind, and resumes
+    from it bit-identically."""
+    server = StreamServer(resident_pipe, RESIDENT_GATE)
+    for sid in ("c0", "c1", "c2"):
+        server.add_stream(sid, "cls")
+    cam = SyntheticMovingObject((H, W), seed=8, radius=4.0)
+    frames = iter(cam.frame_at(t) for t in range(16))
+    list(server.run([{sid: next(frames) for sid in ("c0", "c1", "c2")}
+                     for _ in range(2)]))
+    before = server.sessions["c1"]._prev
+    list(server.run([{sid: next(frames) for sid in ("c0", "c2")}]))
+    c1 = server.sessions["c1"]
+    assert isinstance(c1._prev_src, jax.Array)
+    assert c1._prev_src.shape == (_spec().eff_h, _spec().eff_w)
+    np.testing.assert_array_equal(c1._prev, before)
+    stack = server.sessions["c0"]._prev_src[0]
+    assert [s.stream_id for s in stack.sessions] == ["c0", "c2"]

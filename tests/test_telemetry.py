@@ -100,7 +100,7 @@ def test_span_nesting_across_segments(served):
 
 # -- per-tick phase spans and host<->device byte counters ----------------------
 
-PHASES = ("gate", "stage", "frontend", "head")
+PHASES = ("stage", "gate", "frontend", "head")
 N_CAMS, N_TICKS = 3, 6
 
 
@@ -142,12 +142,12 @@ def _reckoned_bytes(server) -> tuple[int, int]:
     keep = h_o * w_o                                   # bool window keep grid
     padded = 4                                         # pow-2 batch of 3
     assert server.sessions["cam0"]._prev.nbytes == eff
-    first_h2d, first_d2h = N_CAMS * frame, N_CAMS * eff          # solo eff()
-    gate_h2d = (N_TICKS - 1) * N_CAMS * (eff + frame)            # step_batch
-    gate_d2h = (N_TICKS - 1) * N_CAMS * (eff + grid)
+    # the frames go up once a tick and feed gate and frontend; previous
+    # effective frames stay on the device, only the |Δ| grid comes back
+    gate_d2h = N_TICKS * N_CAMS * grid                           # step_batch
     tick_h2d = N_TICKS * (N_CAMS * frame + padded * keep + N_CAMS * keep)
     tick_d2h = N_TICKS * N_CAMS * (4 * h_o * w_o * 4 + 4 * 3)   # counts, logits
-    return (first_h2d + gate_h2d + tick_h2d, first_d2h + gate_d2h + tick_d2h)
+    return tick_h2d, gate_d2h + tick_d2h
 
 
 @pytest.fixture(scope="module")
@@ -156,7 +156,7 @@ def fleet_spans(tmp_path_factory):
 
 
 def test_phase_spans_nest_under_serve_tick(fleet_spans):
-    """gate, stage, frontend and head run once per tick, in that order,
+    """stage, gate, frontend and head run once per tick, in that order,
     inside their serve_tick; every record carries t0_ns."""
     _, _, spans = fleet_spans
     ticks = [s for s in spans if s["span"] == "serve_tick"]
