@@ -171,12 +171,14 @@ def head_flops(model) -> dict:
     :class:`repro.fpca.FPCAModelProgram` (one frame through the head).
 
     Returns per-layer ``(kind, macs, params)`` rows plus totals; pooling and
-    activation stages count as element ops, not MACs.
+    activation stages count as element ops, not MACs.  A grouped conv costs
+    ``h_o * w_o * k * k * (c_in / groups) * c_out`` MACs (a depthwise conv
+    ``h_o * w_o * k * k * c``).
 
     Zoo :class:`repro.models.heads.HeadGraph` heads are costed per node in
     topological order: Conv/Dense/Detect nodes carry MACs + params (a
     DetectSpec is a SAME conv emitting ``n_classes + 4`` channels),
-    Add/Concat joins and activations count as element ops.
+    Add/Concat joins, global pools and activations count as element ops.
     """
     from repro.fpca.program import ConvSpec, DenseSpec, PoolSpec
 
@@ -188,7 +190,7 @@ def head_flops(model) -> dict:
     for i, layer in enumerate(model.head):
         cur, nxt = shapes[i], shapes[i + 1]
         if isinstance(layer, ConvSpec):
-            k2c = layer.kernel * layer.kernel * cur[-1]
+            k2c = layer.kernel * layer.kernel * (cur[-1] // layer.groups)
             l_macs = nxt[0] * nxt[1] * nxt[2] * k2c
             l_params = layer.out_channels * (k2c + 1)
             # fused activations cost the same element ops as standalone
@@ -227,7 +229,7 @@ def head_flops(model) -> dict:
 def _graph_head_flops(model) -> dict:
     """Per-node cost of a :class:`repro.models.heads.HeadGraph` head."""
     from repro.fpca.program import ConvSpec, DenseSpec, PoolSpec
-    from repro.models.heads import AddSpec, ConcatSpec, DetectSpec
+    from repro.models.heads import AddSpec, ConcatSpec, DetectSpec, GlobalPoolSpec
 
     graph = model.head
     shapes = graph.shapes(model.frontend.out_shape)
@@ -239,7 +241,7 @@ def _graph_head_flops(model) -> dict:
         nxt = shapes[node.name]
         if isinstance(op, (ConvSpec, DetectSpec)):
             kernel = op.kernel
-            k2c = kernel * kernel * cur[-1]
+            k2c = kernel * kernel * (cur[-1] // getattr(op, "groups", 1))
             l_macs = nxt[0] * nxt[1] * nxt[2] * k2c
             l_params = op.out_channels * (k2c + 1)
             act = getattr(op, "activation", None)
@@ -254,6 +256,9 @@ def _graph_head_flops(model) -> dict:
         elif isinstance(op, PoolSpec):
             l_macs = l_params = 0
             l_elem = nxt[0] * nxt[1] * nxt[2] * op.size * op.size
+        elif isinstance(op, GlobalPoolSpec):
+            l_macs = l_params = 0
+            l_elem = int(np.prod(cur))
         elif isinstance(op, (AddSpec, ConcatSpec)):
             l_macs = l_params = 0
             # one element op per joined input element (+ the activation)

@@ -84,6 +84,7 @@ from repro.models.heads import (
     ConcatSpec,
     DetectSpec,
     Detections,
+    GlobalPoolSpec,
     HeadGraph,
     Node,
 )
@@ -121,6 +122,7 @@ __all__ = [
     "Node",
     "AddSpec",
     "ConcatSpec",
+    "GlobalPoolSpec",
     "DetectSpec",
     "Detections",
     # re-exported building blocks of a program

@@ -284,7 +284,7 @@ class ProgrammedConfig:
 # the executable (they extend the signature), trained *parameters* enter
 # traced (reprogramming them never recompiles).
 
-_ACTIVATIONS = ("relu", "gelu", "silu", "tanh")
+_ACTIVATIONS = ("relu", "gelu", "silu", "tanh", "relu6")
 
 
 def _check_activation(act: str | None) -> None:
@@ -305,29 +305,57 @@ def _apply_activation(act: str | None, x):
         "gelu": jax.nn.gelu,
         "silu": jax.nn.silu,
         "tanh": jnp.tanh,
+        "relu6": jax.nn.relu6,
     }[act](x)
 
 
 @dataclasses.dataclass(frozen=True)
 class ConvSpec:
-    """One digital convolution stage of a model head (NHWC, biased)."""
+    """One digital convolution stage of a model head (NHWC, biased).
+
+    ``groups`` splits the input and output channels into that many
+    independent convolutions (``lax.conv_general_dilated``'s
+    ``feature_group_count``); ``groups == in_channels == out_channels`` is a
+    depthwise convolution.  Weights are ``(out_channels, k, k, in_channels
+    // groups)``."""
 
     out_channels: int
     kernel: int
     stride: int = 1
     padding: str = "VALID"          # "VALID" | "SAME"
     activation: str | None = "relu"
+    groups: int = 1
 
     def __post_init__(self) -> None:
         if self.out_channels < 1 or self.kernel < 1 or self.stride < 1:
             raise ValueError("conv out_channels/kernel/stride must be >= 1")
         if self.padding not in ("VALID", "SAME"):
             raise ValueError(f"padding must be VALID or SAME, got {self.padding!r}")
+        if self.groups < 1:
+            raise ValueError(f"conv groups must be >= 1, got {self.groups}")
+        if self.out_channels % self.groups:
+            raise ValueError(
+                f"conv groups {self.groups} do not divide out_channels "
+                f"{self.out_channels}"
+            )
         _check_activation(self.activation)
 
+    def weight_shape(self, c_in: int, where: str) -> tuple[int, int, int, int]:
+        """``(out_channels, k, k, c_in // groups)`` for an input of ``c_in``
+        channels; ``where`` names the stage in the divisibility error."""
+        if c_in % self.groups:
+            raise ValueError(
+                f"{where}: conv groups {self.groups} do not divide input "
+                f"channels {c_in}"
+            )
+        return (self.out_channels, self.kernel, self.kernel, c_in // self.groups)
+
     def _sig(self) -> tuple:
-        return ("conv", int(self.out_channels), int(self.kernel),
-                int(self.stride), self.padding, self.activation or "")
+        sig = ("conv", int(self.out_channels), int(self.kernel),
+               int(self.stride), self.padding, self.activation or "")
+        # appended only for grouped convs, so every ungrouped signature
+        # stays byte-identical (golden-pinned)
+        return sig + (("groups", int(self.groups)),) if self.groups != 1 else sig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -439,7 +467,14 @@ class FPCAModelProgram:
                 raise ValueError("input_scale must be > 0")
             # validates node geometry against the frontend's output shape
             self.head.shapes(self.frontend.out_shape)
-            return
+        else:
+            self._validate_chain()
+        if self.precision == "int8":
+            from repro.models.quant import check_int8_lowering
+
+            check_int8_lowering(self)
+
+    def _validate_chain(self) -> None:
         object.__setattr__(self, "head", tuple(self.head))
         if not self.head:
             raise ValueError("model head needs at least one layer spec")
@@ -478,7 +513,8 @@ class FPCAModelProgram:
                         f"head[{i}]: conv needs a spatial (h, w, c) input, "
                         f"got shape {cur}"
                     )
-                h, w, _ = cur
+                h, w, c = cur
+                layer.weight_shape(c, f"head[{i}]")
                 if layer.padding == "SAME":
                     h_o = -(-h // layer.stride)
                     w_o = -(-w // layer.stride)
@@ -563,7 +599,8 @@ class FPCAModelProgram:
             cur = shapes[i]
             if isinstance(layer, ConvSpec):
                 params.append(
-                    init_conv2d(keys[i], cur[-1], layer.out_channels, layer.kernel)
+                    init_conv2d(keys[i], cur[-1], layer.out_channels, layer.kernel,
+                                groups=layer.groups)
                 )
             elif isinstance(layer, DenseSpec):
                 d_in = 1
@@ -616,8 +653,7 @@ class FPCAModelProgram:
         for i, (layer, p) in enumerate(zip(self.head, bound)):
             cur = shapes[i]
             if isinstance(layer, ConvSpec):
-                want = {"w": (layer.out_channels, layer.kernel, layer.kernel,
-                              cur[-1]),
+                want = {"w": layer.weight_shape(cur[-1], f"head[{i}]"),
                         "b": (layer.out_channels,)}
             elif isinstance(layer, DenseSpec):
                 d_in = 1
@@ -668,7 +704,8 @@ class FPCAModelProgram:
         for layer, p in zip(self.head, params):
             if isinstance(layer, ConvSpec):
                 x = _apply_activation(
-                    layer.activation, conv2d(p, x, layer.stride, layer.padding)
+                    layer.activation,
+                    conv2d(p, x, layer.stride, layer.padding, layer.groups),
                 )
             elif isinstance(layer, PoolSpec):
                 pool = max_pool2d if layer.kind == "max" else avg_pool2d

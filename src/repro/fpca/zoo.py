@@ -8,7 +8,7 @@ stamped with ``arch=name`` so model-side telemetry (the ``fpca_model_*``
 families in :mod:`repro.fpca.executable`) and ``fleet_report()`` break out
 workloads per architecture.
 
-Three architectures ship registered:
+Four architectures ship registered:
 
 * ``"fpca_cnn"`` — the repo's original sequential classifier, *unchanged*:
   the builder constructs the exact same chain-head tuple as
@@ -19,7 +19,11 @@ Three architectures ship registered:
   :class:`repro.models.heads.HeadGraph` (conv trunk, post-add relu join);
 * ``"fpca_detect"`` — a detection head: per-coarse-cell class scores + box
   regression (:class:`repro.models.heads.DetectSpec`), streaming per-tick
-  :class:`repro.models.heads.Detections` through ``serve`` / ``run_segment``.
+  :class:`repro.models.heads.Detections` through ``serve`` / ``run_segment``;
+* ``"fpca_mobilenetv2"`` — P2M's published VWW network: MobileNetV2's
+  inverted-residual bottleneck stack behind the in-pixel layer (grouped /
+  depthwise :class:`repro.fpca.ConvSpec`, ``relu6``, residual joins, a
+  global average pool).
 
 ``cfg`` keys every builder understands: ``spec`` (an
 :class:`repro.core.mapping.FPCASpec` or kwargs mapping; defaults to the
@@ -41,7 +45,7 @@ from repro.fpca.program import (
     FPCAProgram,
     PoolSpec,
 )
-from repro.models.heads import AddSpec, DetectSpec, HeadGraph, Node
+from repro.models.heads import AddSpec, DetectSpec, GlobalPoolSpec, HeadGraph, Node
 
 __all__ = ["register_arch", "build_model", "available_archs"]
 
@@ -184,5 +188,70 @@ def _build_fpca_detect(cfg: Mapping) -> FPCAModelProgram:
     return FPCAModelProgram(
         frontend=_frontend(cfg),
         head=graph,
+        input_scale=float(cfg.get("input_scale", 1.0)),
+    )
+
+
+# MobileNetV2 (Sandler et al., arXiv:1801.04381, Table 2) after its stem:
+# (expansion t, output channels c, repeats n, first stride s) per sequence.
+_MOBILENETV2_BLOCKS = (
+    (1, 16, 1, 1),
+    (6, 24, 2, 2),
+    (6, 32, 3, 2),
+    (6, 64, 4, 2),
+    (6, 96, 3, 1),
+    (6, 160, 3, 2),
+    (6, 320, 1, 1),
+)
+_MOBILENETV2_LAST = 1280
+
+
+@register_arch("fpca_mobilenetv2")
+def _build_fpca_mobilenetv2(cfg: Mapping) -> FPCAModelProgram:
+    """P2M's VWW network (arXiv:2203.04737): MobileNetV2 at width multiplier
+    1.0 with its stem (3x3 stride-2 conv to 32 channels) replaced by the
+    in-pixel layer, so the bottleneck stack starts from the frontend's
+    count map.  Table 2's 17 inverted-residual blocks ``block_0`` ..
+    ``block_16``: a 1x1 ``_expand`` conv to ``t * c_in`` channels with relu6
+    (absent where ``t == 1``), a 3x3 ``_depthwise`` conv (stride 1 or 2)
+    with relu6, a linear 1x1 ``_project`` conv, and an ``_add`` residual
+    join where the stride is 1 and the width unchanged (10 of the 17);
+    then ``conv_1`` (1x1 to 1280, relu6), a global average ``pool`` and
+    Dense ``logits``.  Knobs: ``n_classes`` (default 2, VWW's person / no
+    person) and ``input_scale``.
+
+    Departures from the paper: BatchNorm is folded into the conv biases
+    (inference); there is no dropout; the depthwise convs pad ``SAME``, so
+    the stride-2 ones pad as the TF reference implementation does (PyTorch
+    pads 1 on both sides; both give the same output size)."""
+    frontend = _frontend(cfg)
+    nodes = []
+    x, c_in, i = "input", frontend.out_channels, 0
+    for t, c, n, s in _MOBILENETV2_BLOCKS:
+        for r in range(n):
+            name, stride, hidden = f"block_{i}", s if r == 0 else 1, t * c_in
+            y = x
+            if t != 1:
+                nodes.append(Node(f"{name}_expand",
+                                  ConvSpec(hidden, 1, activation="relu6"), (y,)))
+                y = f"{name}_expand"
+            nodes.append(Node(f"{name}_depthwise",
+                              ConvSpec(hidden, 3, stride=stride, padding="SAME",
+                                       activation="relu6", groups=hidden), (y,)))
+            nodes.append(Node(f"{name}_project", ConvSpec(c, 1, activation=None),
+                              (f"{name}_depthwise",)))
+            y = f"{name}_project"
+            if stride == 1 and c_in == c:
+                nodes.append(Node(f"{name}_add", AddSpec(), (x, y)))
+                y = f"{name}_add"
+            x, c_in, i = y, c, i + 1
+    nodes += [
+        Node("conv_1", ConvSpec(_MOBILENETV2_LAST, 1, activation="relu6"), (x,)),
+        Node("pool", GlobalPoolSpec(), ("conv_1",)),
+        Node("logits", DenseSpec(int(cfg.get("n_classes", 2))), ("pool",)),
+    ]
+    return FPCAModelProgram(
+        frontend=frontend,
+        head=HeadGraph(nodes=tuple(nodes), output="logits"),
         input_scale=float(cfg.get("input_scale", 1.0)),
     )

@@ -17,9 +17,10 @@ outputs need a *graph*.  :class:`HeadGraph` is that IR:
   trace, exactly like ``FPCAModelProgram.apply_head``).
 
 Graph-only ops live here: :class:`AddSpec` (elementwise residual join),
-:class:`ConcatSpec` (channel concat) and :class:`DetectSpec` (per-coarse-cell
-class scores + box regression).  A graph whose output node is a
-:class:`DetectSpec` makes the model a *detection* workload: its raw
+:class:`ConcatSpec` (channel concat), :class:`GlobalPoolSpec` (mean over
+the whole final map, whatever the frame size) and :class:`DetectSpec`
+(per-coarse-cell class scores + box regression).  A graph whose output
+node is a :class:`DetectSpec` makes the model a *detection* workload: its raw
 ``(gh, gw, n_classes + 4)`` maps are split into :class:`Detections` at the
 user-facing boundaries (``CompiledModel.run`` / ``stream`` /
 ``run_segment``).
@@ -45,6 +46,7 @@ from repro.fpca.program import (
 __all__ = [
     "AddSpec",
     "ConcatSpec",
+    "GlobalPoolSpec",
     "DetectSpec",
     "Node",
     "HeadGraph",
@@ -92,6 +94,16 @@ class ConcatSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class GlobalPoolSpec:
+    """Global average pool: the mean over the whole ``(h, w)`` map of its
+    input, ``(h, w, c) -> (c,)``, so a classifier's last spatial stage
+    reduces to one vector whatever the frame size left."""
+
+    def _sig(self) -> tuple:
+        return ("global_pool", "avg")
+
+
+@dataclasses.dataclass(frozen=True)
 class DetectSpec:
     """Per-coarse-cell detection output: ``n_classes`` class scores plus 4
     box-regression channels per spatial cell of its input — a ``kernel`` x
@@ -117,7 +129,7 @@ class DetectSpec:
         return ("detect", int(self.n_classes), int(self.kernel))
 
 
-_CHAIN_OPS = (ConvSpec, PoolSpec, DenseSpec, ActivationSpec)
+_CHAIN_OPS = (ConvSpec, PoolSpec, GlobalPoolSpec, DenseSpec, ActivationSpec)
 _JOIN_OPS = (AddSpec, ConcatSpec)
 _PARAM_OPS = (ConvSpec, DenseSpec, DetectSpec)
 _ALL_OPS = _CHAIN_OPS + _JOIN_OPS + (DetectSpec,)
@@ -168,7 +180,8 @@ def _chain_out_shape(op: Any, cur: tuple[int, ...], where: str) -> tuple:
                 f"{where}: conv needs a spatial (h, w, c) input, got shape "
                 f"{cur}"
             )
-        h, w, _ = cur
+        h, w, c = cur
+        op.weight_shape(c, where)
         if op.padding == "SAME":
             return (-(-h // op.stride), -(-w // op.stride), op.out_channels)
         if op.kernel > h or op.kernel > w:
@@ -197,6 +210,13 @@ def _chain_out_shape(op: Any, cur: tuple[int, ...], where: str) -> tuple:
             )
         s = op.size if op.stride is None else op.stride
         return ((h - op.size) // s + 1, (w - op.size) // s + 1, c)
+    if isinstance(op, GlobalPoolSpec):
+        if len(cur) != 3:
+            raise ValueError(
+                f"{where}: global pool needs a spatial (h, w, c) input, got "
+                f"shape {cur}"
+            )
+        return (cur[-1],)
     if isinstance(op, DenseSpec):
         return (op.features,)
     return tuple(cur)                       # ActivationSpec: shape-preserving
@@ -359,7 +379,10 @@ class HeadGraph:
         self, node: Node, shapes: dict[str, tuple[int, ...]]
     ) -> dict[str, tuple[int, ...]]:
         op, cur = node.op, shapes[node.inputs[0]]
-        if isinstance(op, (ConvSpec, DetectSpec)):
+        if isinstance(op, ConvSpec):
+            return {"w": op.weight_shape(cur[-1], f"node {node.name!r}"),
+                    "b": (op.out_channels,)}
+        if isinstance(op, DetectSpec):
             c_out = op.out_channels
             return {"w": (c_out, op.kernel, op.kernel, cur[-1]),
                     "b": (c_out,)}
@@ -382,7 +405,8 @@ class HeadGraph:
             op = node.op
             if isinstance(op, (ConvSpec, DetectSpec)):
                 params[node.name] = init_conv2d(
-                    k, cur[-1], op.out_channels, op.kernel
+                    k, cur[-1], op.out_channels, op.kernel,
+                    groups=getattr(op, "groups", 1),
                 )
             else:
                 d_in = 1
@@ -433,7 +457,7 @@ class HeadGraph:
         import jax.numpy as jnp
 
         from repro.models.layers import (
-            avg_pool2d, conv2d, linear, max_pool2d,
+            avg_pool2d, conv2d, global_avg_pool2d, linear, max_pool2d,
         )
 
         if x.ndim == 3:
@@ -445,13 +469,16 @@ class HeadGraph:
             if isinstance(op, ConvSpec):
                 y = _apply_activation(
                     op.activation,
-                    conv2d(params[node.name], ins[0], op.stride, op.padding),
+                    conv2d(params[node.name], ins[0], op.stride, op.padding,
+                           op.groups),
                 )
             elif isinstance(op, DetectSpec):
                 y = conv2d(params[node.name], ins[0], 1, "SAME")
             elif isinstance(op, PoolSpec):
                 pool = max_pool2d if op.kind == "max" else avg_pool2d
                 y = pool(ins[0], op.size, op.stride)
+            elif isinstance(op, GlobalPoolSpec):
+                y = global_avg_pool2d(ins[0])
             elif isinstance(op, DenseSpec):
                 v = ins[0]
                 if v.ndim > 2:

@@ -34,6 +34,7 @@ __all__ = [
     "linear",
     "max_pool2d",
     "avg_pool2d",
+    "global_avg_pool2d",
 ]
 
 
@@ -145,27 +146,38 @@ def unembed(params: dict, x: jax.Array) -> jax.Array:
 
 
 def init_conv2d(
-    key: jax.Array, c_in: int, c_out: int, kernel: int, dtype=jnp.float32
+    key: jax.Array, c_in: int, c_out: int, kernel: int, dtype=jnp.float32,
+    groups: int = 1,
 ) -> dict:
-    """Biased conv params: ``w`` is ``(c_out, k, k, c_in)`` (FPCA kernel
-    layout, so frontend and head convolutions read the same way)."""
-    fan_in = kernel * kernel * c_in
-    w = jax.random.normal(key, (c_out, kernel, kernel, c_in)) * fan_in ** -0.5
+    """Biased conv params: ``w`` is ``(c_out, k, k, c_in // groups)`` (FPCA
+    kernel layout, so frontend and head convolutions read the same way);
+    each output channel's fan-in is ``k * k * c_in / groups``."""
+    fan_in = kernel * kernel * (c_in // groups)
+    w = jax.random.normal(key, (c_out, kernel, kernel, c_in // groups)) * fan_in ** -0.5
     return {"w": w.astype(dtype), "b": jnp.zeros((c_out,), dtype)}
 
 
 def conv2d(
-    params: dict, x: jax.Array, stride: int = 1, padding: str = "VALID"
+    params: dict, x: jax.Array, stride: int = 1, padding: str = "VALID",
+    groups: int = 1,
 ) -> jax.Array:
-    """NHWC convolution with bias; ``padding`` is ``"VALID"`` or ``"SAME"``."""
+    """NHWC convolution with bias; ``padding`` is ``"VALID"`` or ``"SAME"``;
+    ``groups`` independent channel groups (``groups == c_in == c_out`` is
+    depthwise)."""
     out = jax.lax.conv_general_dilated(
         x.transpose(0, 3, 1, 2),
         params["w"].transpose(0, 3, 1, 2),
         window_strides=(stride, stride),
         padding=padding,
         dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        feature_group_count=groups,
     ).transpose(0, 2, 3, 1)
     return out + params["b"]
+
+
+def global_avg_pool2d(x: jax.Array) -> jax.Array:
+    """Mean over the whole ``(h, w)`` map of an NHWC batch: ``(b, c)``."""
+    return jnp.mean(x, axis=(1, 2))
 
 
 def init_linear(key: jax.Array, d_in: int, d_out: int, dtype=jnp.float32) -> dict:

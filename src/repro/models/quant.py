@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
+    "check_int8_lowering",
     "quantize_symmetric",
     "quantize_leaf_symmetric",
     "dequantize_leaf",
@@ -184,6 +185,25 @@ def conv2d_int8(
     return acc.astype(jnp.float32) * (qp["x_scale"] * qp["w_scale"]) + qp["b"]
 
 
+def check_int8_lowering(program) -> None:
+    """Refuse a head this module cannot lower: a grouped (depthwise)
+    convolution has no int8 path here, so ``precision="int8"`` on such a
+    model fails at construction, naming the stage, instead of serving a
+    lowering that ignores its groups."""
+    from repro.fpca.program import ConvSpec
+
+    if program.is_graph_head:
+        stages = [(f"head node {n.name!r}", n.op) for n in program.head.nodes]
+    else:
+        stages = [(f"head[{i}]", op) for i, op in enumerate(program.head)]
+    for where, op in stages:
+        if isinstance(op, ConvSpec) and op.groups != 1:
+            raise ValueError(
+                f"{where}: grouped convolution (groups={op.groups}) has no "
+                f"int8 lowering; serve this model with precision='f32'"
+            )
+
+
 # ---------------------------------------------------------------------------
 # calibration
 # ---------------------------------------------------------------------------
@@ -247,8 +267,12 @@ def _calibrate_graph(graph, params: Any, x: jax.Array) -> dict[str, float]:
     from repro.fpca.program import (
         ConvSpec, DenseSpec, PoolSpec, _apply_activation,
     )
-    from repro.models.heads import INPUT, AddSpec, ConcatSpec, DetectSpec
-    from repro.models.layers import avg_pool2d, conv2d, linear, max_pool2d
+    from repro.models.heads import (
+        INPUT, AddSpec, ConcatSpec, DetectSpec, GlobalPoolSpec,
+    )
+    from repro.models.layers import (
+        avg_pool2d, conv2d, global_avg_pool2d, linear, max_pool2d,
+    )
 
     values: dict[str, Any] = {INPUT: x}
     scales: dict[str, float] = {}
@@ -267,6 +291,8 @@ def _calibrate_graph(graph, params: Any, x: jax.Array) -> dict[str, float]:
         elif isinstance(op, PoolSpec):
             pool = max_pool2d if op.kind == "max" else avg_pool2d
             y = pool(ins[0], op.size, op.stride)
+        elif isinstance(op, GlobalPoolSpec):
+            y = global_avg_pool2d(ins[0])
         elif isinstance(op, DenseSpec):
             v = ins[0]
             if v.ndim > 2:
@@ -481,8 +507,10 @@ def _apply_graph_int8(graph, params: Any, x: jax.Array) -> jax.Array:
     from repro.fpca.program import (
         ConvSpec, DenseSpec, PoolSpec, _apply_activation,
     )
-    from repro.models.heads import INPUT, AddSpec, ConcatSpec, DetectSpec
-    from repro.models.layers import avg_pool2d, max_pool2d
+    from repro.models.heads import (
+        INPUT, AddSpec, ConcatSpec, DetectSpec, GlobalPoolSpec,
+    )
+    from repro.models.layers import avg_pool2d, global_avg_pool2d, max_pool2d
 
     if x.ndim == 3:
         return _apply_graph_int8(graph, params, x[None])[0]
@@ -500,6 +528,8 @@ def _apply_graph_int8(graph, params: Any, x: jax.Array) -> jax.Array:
         elif isinstance(op, PoolSpec):
             pool = max_pool2d if op.kind == "max" else avg_pool2d
             y = pool(ins[0], op.size, op.stride)
+        elif isinstance(op, GlobalPoolSpec):
+            y = global_avg_pool2d(ins[0])
         elif isinstance(op, DenseSpec):
             v = ins[0]
             if v.ndim > 2:
