@@ -18,7 +18,7 @@ Everything in this module depends only on :mod:`repro.core.mapping` (no
 serving imports), so the backend registry can build scan bodies from it
 without import cycles.
 
-State-machine semantics (mirrors ``streaming._GateState.step`` exactly):
+State-machine semantics (mirrors ``streaming._step_gates`` exactly):
 
 * block ages start at ``hysteresis + 1`` (everything stale);
 * a block's age resets to 0 when its mean |Δ| exceeds the threshold, else

@@ -30,12 +30,16 @@ Key hardware facts encoded (and tested):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Iterator
 
 import numpy as np
 
-__all__ = ["FPCASpec", "Cycle", "n_cycles", "output_dims", "schedule", "active_window_mask"]
+__all__ = [
+    "FPCASpec", "Cycle", "n_cycles", "output_dims", "schedule",
+    "active_window_mask", "active_window_masks",
+]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -157,27 +161,64 @@ def active_window_mask(spec: FPCASpec, block_mask: np.ndarray | None) -> np.ndar
     executes iff *any* of its pixels lies in a kept block (RS/SW lines for
     fully-skipped regions are never raised).
 
-    Returns a boolean ``(h_o, w_o)`` mask.
+    Returns a boolean ``(h_o, w_o)`` mask: row 0 of
+    :func:`active_window_masks` on the one grid.
     """
-    h_o, w_o = output_dims(spec)
     if block_mask is None:
-        return np.ones((h_o, w_o), dtype=bool)
+        return np.ones(output_dims(spec), dtype=bool)
+    return active_window_masks(spec, np.asarray(block_mask)[None])[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _window_block_taps(
+    spec: FPCASpec,
+) -> tuple[tuple[tuple[np.ndarray, ...], np.ndarray | None], ...]:
+    """Per axis (rows, then columns): the block indices each output window
+    reads, and which windows hold any in-bounds pixel (None when all do).
+
+    Window ``r`` covers pixels ``r*s .. min(r*s + n, eff) - 1`` (offsets
+    ignore ``padding``; a footprint past the frame is clipped), a
+    contiguous run of blocks ``lo .. hi``.  Tap ``k`` is block
+    ``min(lo + k, hi)``, so ORing the taps ORs exactly the run."""
+    n, s, b = spec.max_kernel, spec.stride, spec.skip_block
+    axes = []
+    for eff, out in zip((spec.eff_h, spec.eff_w), output_dims(spec)):
+        start = np.arange(out) * s
+        stop = np.minimum(start + n, eff)
+        inside = stop > start
+        lo = np.where(inside, start // b, 0)
+        hi = np.where(inside, (stop - 1) // b, 0)
+        taps = tuple(
+            np.minimum(lo + k, hi) for k in range(int((hi - lo).max()) + 1)
+        )
+        axes.append((taps, None if inside.all() else inside))
+    return tuple(axes)
+
+
+def active_window_masks(spec: FPCASpec, block_masks: np.ndarray) -> np.ndarray:
+    """:func:`active_window_mask` over a stack of block grids: ``(n, bh,
+    bw)`` -> ``(n, h_o, w_o)`` booleans, row ``j`` the windows that grid
+    ``j`` executes.  A window reads the run of blocks its footprint covers
+    on each axis (:func:`_window_block_taps`), so the grid is an OR of a
+    few shifted gathers per axis; a window with no in-bounds pixel is not
+    kept."""
+    masks = np.asarray(block_masks, dtype=bool)
     b = spec.skip_block
-    exp_h, exp_w = math.ceil(spec.eff_h / b), math.ceil(spec.eff_w / b)
-    if block_mask.shape != (exp_h, exp_w):
-        raise ValueError(f"block_mask shape {block_mask.shape} != {(exp_h, exp_w)}")
-    pixel_keep = np.kron(block_mask, np.ones((b, b), dtype=bool))[: spec.eff_h, : spec.eff_w]
-    n, s = spec.max_kernel, spec.stride
-    if (h_o - 1) * s + n <= spec.eff_h and (w_o - 1) * s + n <= spec.eff_w:
-        # no padding: every window footprint is in-bounds — vectorised form
-        # (the streaming hot path gates every frame of every stream here)
-        windows = np.lib.stride_tricks.sliding_window_view(pixel_keep, (n, n))
-        return windows[::s, ::s].any(axis=(2, 3))[:h_o, :w_o]
-    mask = np.zeros((h_o, w_o), dtype=bool)
-    for r in range(h_o):
-        for c in range(w_o):
-            mask[r, c] = pixel_keep[r * s : r * s + n, c * s : c * s + n].any()
-    return mask
+    exp = (math.ceil(spec.eff_h / b), math.ceil(spec.eff_w / b))
+    if masks.ndim != 3 or masks.shape[1:] != exp:
+        raise ValueError(f"block_mask shape {masks.shape[1:]} != {exp}")
+    (row_taps, row_in), (col_taps, col_in) = _window_block_taps(spec)
+    rows = masks[:, row_taps[0]]
+    for idx in row_taps[1:]:
+        rows |= masks[:, idx]
+    if row_in is not None:
+        rows &= row_in[:, None]
+    out = rows[:, :, col_taps[0]]
+    for idx in col_taps[1:]:
+        out |= rows[:, :, idx]
+    if col_in is not None:
+        out &= col_in
+    return out
 
 
 def n_cycles_with_skipping(spec: FPCASpec, block_mask: np.ndarray | None) -> int:

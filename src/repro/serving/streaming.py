@@ -158,7 +158,9 @@ def block_delta_mask(
 
 class _GateState:
     """Delta-gate state for one configuration of one stream: its own gate
-    knobs, block-age grid, servo controller and retained mask history."""
+    knobs, block-age grid, servo controller and retained mask history.
+    :func:`_step_gates` advances it as one row of its group's arrays, so
+    its grids are views of those rows."""
 
     def __init__(
         self,
@@ -188,53 +190,89 @@ class _GateState:
             maxlen=history
         )
 
-    def step(
-        self,
-        spec: mapping.FPCASpec,
-        delta_blocks: np.ndarray | None,
-        frame_idx: int,
-    ) -> np.ndarray:
-        """Advance this config's gate by one frame (``delta_blocks`` is the
-        shared per-block |Δ| grid, ``None`` on the first frame)."""
-        if delta_blocks is not None:
-            # float32 threshold on BOTH sides (numpy promotes the comparison
-            # otherwise) — the same comparison the in-scan gate traces, so a
-            # delta within 1 ulp of the threshold decides identically
-            changed = delta_blocks > np.float32(self.gate.threshold)
-            self.age = np.where(changed, 0, self.age + 1)
-            self.last_changed = changed
-            self.changed_total += int(changed.sum())
-        else:
-            self.last_changed = None
-        keyframe = delta_blocks is None or (
-            self.gate.keyframe_interval > 0
-            and frame_idx % self.gate.keyframe_interval == 0
-        )
-        keep = (
-            np.ones_like(self.age, bool)
-            if keyframe
-            else self.age <= self.gate.hysteresis
-        )
-        self.last_keyframe = keyframe
-        self.last_block_mask = keep
-        self.block_masks.append(keep)
-        # derive the per-window keep grid ONCE per frame: the dispatch loop
-        # reuses it (last_window_mask) and the keep-metric servo observes its
-        # mean instead of re-deriving it
-        window = mapping.active_window_mask(spec, keep)
-        self.last_window_mask = window
-        if self.controller is not None:
+def _step_gates(
+    spec: mapping.FPCASpec,
+    sessions: Sequence["StreamSession"],
+    deltas: np.ndarray,
+    has_delta: np.ndarray,
+    currents: Sequence[Any],
+    signed: np.ndarray | None,
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Advance the delta gates of gated ``sessions`` (all on ``spec``) by
+    one frame in one host pass.
+
+    ``deltas[j]`` is session ``j``'s block |Δ| grid, read where
+    ``has_delta[j]`` (False on a stream's first frame); ``currents[j]`` is
+    where its new effective frame lives and ``signed[j]`` its signed
+    block-mean Δ (None when no session wants events).
+
+    Each gate state (one per session, or one per config of a per-config
+    session) is one row of ``(rows, bh, bw)`` arrays: the float32 threshold
+    compare, ages, keyframe, keep and window grids are computed for all rows
+    at once, and each state's attributes become views of its row.  A block
+    is kept iff it changed within the last ``hysteresis + 1`` frames;
+    keyframes (the first frame, then every ``keyframe_interval``) keep
+    everything but do NOT reset the ages.  Per row, Python only points
+    the state at its views and runs its servo, where it has one.  Returns, per session, the block keep mask
+    ``(m, bh, bw)``, the window keep grid ``(m, h_o, w_o)`` and the kept
+    window count ``(m,)`` — unions over a per-config session's states."""
+    states = [st for s in sessions for st in s._states]
+    per = [len(s._states) for s in sessions]
+    frame_idx = np.array([s.frame_idx for s in sessions], np.int64)
+    if len(states) != len(sessions):
+        deltas = np.repeat(deltas, per, axis=0)
+        has_delta = np.repeat(has_delta, per)
+        frame_idx = np.repeat(frame_idx, per)
+    gates = [st.gate for st in states]
+    # float32 threshold on BOTH sides (numpy promotes the comparison
+    # otherwise) — the same comparison the in-scan gate traces, so a delta
+    # within 1 ulp of the threshold decides identically
+    thr = np.array([g.threshold for g in gates], np.float32)
+    hyst = np.array([g.hysteresis for g in gates], np.int64)
+    interval = np.array([g.keyframe_interval for g in gates], np.int64)
+    has = has_delta[:, None, None]
+    changed = (deltas > thr[:, None, None]) & has
+    ages = np.where(changed, 0, np.stack([st.age for st in states]) + has)
+    keyframe = ~has_delta | (
+        (interval > 0) & (frame_idx % np.maximum(interval, 1) == 0)
+    )
+    keep = keyframe[:, None, None] | (ages <= hyst[:, None, None])
+    windows = mapping.active_window_masks(spec, keep)
+    n_changed = np.count_nonzero(changed, axis=(1, 2))
+    for j, st in enumerate(states):
+        # changed-block accounting the event tap reconciles against
+        st.last_changed = changed[j] if has_delta[j] else None
+        st.changed_total += int(n_changed[j])
+        st.age = ages[j]
+        st.last_keyframe = bool(keyframe[j])
+        st.last_block_mask = keep[j]
+        st.block_masks.append(keep[j])
+        st.last_window_mask = windows[j]
+        if st.controller is not None:
             obs = (
-                float(window.mean())
-                if self.controller.config.metric == "keep"
+                float(windows[j].mean())
+                if st.controller.config.metric == "keep"
                 else None
             )
-            new_thr = self.controller.observe(
-                keep, keyframe=keyframe, observation=obs
+            new_thr = st.controller.observe(
+                keep[j], keyframe=bool(keyframe[j]), observation=obs
             )
-            if new_thr != self.gate.threshold:
-                self.gate = dataclasses.replace(self.gate, threshold=new_thr)
-        return keep
+            if new_thr != st.gate.threshold:
+                st.gate = dataclasses.replace(st.gate, threshold=new_thr)
+    if len(states) != len(sessions):
+        starts = np.cumsum([0] + per[:-1])
+        keep = np.logical_or.reduceat(keep, starts, axis=0)
+        windows = np.logical_or.reduceat(windows, starts, axis=0)
+    for j, session in enumerate(sessions):
+        if session.want_events:
+            # polarity source for the event tap: signed block-mean change
+            session._last_signed = (
+                signed[j] if signed is not None and has_delta[j] else None
+            )
+        session._prev_src = currents[j]
+        session.frame_idx += 1
+        session.last_window_mask = windows[j]
+    return keep, windows, np.count_nonzero(windows, axis=(1, 2))
 
 
 class _PrevStack:
@@ -432,61 +470,37 @@ class StreamSession:
             return stack.prev[row]
         return src
 
-    def step(
-        self,
-        frame: Any,
-        precomputed: tuple[Any, np.ndarray | None, np.ndarray | None]
-        | None = None,
-    ) -> np.ndarray | None:
-        """Advance one frame; returns the block keep mask (None = dense).
+    def step(self, frame: Any) -> np.ndarray | None:
+        """Advance one frame, gated alone; returns the block keep mask
+        (None = dense).
 
-        A block is kept iff it changed within the last ``hysteresis + 1``
-        frames; keyframes (the first frame, then every ``keyframe_interval``)
-        keep everything but do NOT reset the ages — a static scene goes quiet
-        again immediately after the refresh.  With controllers attached, the
-        masks also feed the threshold servo(s), so the NEXT frame gates with
-        the servoed threshold(s).
-
+        With controllers attached, the masks also feed the threshold
+        servo(s), so the NEXT frame gates with the servoed threshold(s).
         With per-config gates, the returned mask (and
         :attr:`last_window_mask`) is the **union** over configs — what the
         fused call must execute; each config's own decision is on its
         :meth:`state_for` entry.
 
-        ``precomputed`` is this tick's ``(effective frame, block |Δ| grid,
-        signed block-mean Δ)`` when the server already gated the stream in
-        a fleet-batched dispatch on the device
-        (:func:`repro.core.gating.HostGateKernels.step_batch` — bit-identical
-        to the solo kernel): the effective frame is where it stays on the
-        device, the ``(stack, row)`` of its group's :class:`_PrevStack`;
-        the grids are host arrays (None on the first frame; the signed grid
-        is None unless :attr:`want_events`).  The per-config threshold comparisons and age
-        bookkeeping still run here, per stream.  Without it the stream is
-        gated alone: the frame (a host or device array) goes to the device,
-        the effective frame stays there, and only the grids come back.
+        The frame (a host or device array) goes to the device, the
+        effective frame stays there, and only the block |Δ| grid (and the
+        signed grid for :attr:`want_events`) comes back; the gate decision
+        is :func:`_step_gates` on this one stream, the pass
+        :class:`StreamServer` runs over a whole group.
         """
         if not self.gating:
             self.frame_idx += 1
             return None
-        if precomputed is not None:
-            cur, delta_blocks, signed = precomputed
+        cur, delta_blocks, signed = self._gate_alone(frame)
+        if delta_blocks is None:
+            delta_blocks = np.zeros(self._primary.age.shape, np.float32)
+            has_delta = np.zeros(1, bool)
         else:
-            cur, delta_blocks, signed = self._gate_alone(frame)
-        if self.want_events:
-            # polarity source for the event tap: signed block-mean change
-            self._last_signed = signed
-        union_keep: np.ndarray | None = None
-        union_window: np.ndarray | None = None
-        for st in self._states:
-            keep = st.step(self.spec, delta_blocks, self.frame_idx)
-            union_keep = keep if union_keep is None else union_keep | keep
-            window = st.last_window_mask
-            union_window = (
-                window if union_window is None else union_window | window
-            )
-        self._prev_src = cur
-        self.frame_idx += 1
-        self.last_window_mask = union_window
-        return union_keep
+            has_delta = np.ones(1, bool)
+        keep, _, _ = _step_gates(
+            self.spec, [self], delta_blocks[None], has_delta, [cur],
+            None if signed is None else signed[None],
+        )
+        return keep[0]
 
     def _gate_alone(self, frame: Any) -> tuple:
         """One gate dispatch for this stream alone (ONE fused kernel per
@@ -884,9 +898,10 @@ class StreamServer:
         batch per configuration group, dispatch without blocking.
 
         Each group's work runs under the telemetry spans ``stage``,
-        ``gate``, ``frontend`` and ``head``, in that order: the group's
-        frames go to the device once, and the gate and the frontend both
-        read that copy."""
+        ``gate`` (``gate_readback``, then ``gate_streams``, inside it),
+        ``frontend`` and ``head``, in that order: the group's frames go to
+        the device once, and the gate and the frontend both read that
+        copy."""
         per_group: dict[tuple[str, ...], list[tuple[StreamSession, np.ndarray]]] = {}
         for stream_id, frame in frames.items():
             session = self.sessions.get(stream_id)
@@ -942,52 +957,69 @@ class StreamServer:
     ) -> tuple[list[dict], np.ndarray | None]:
         """Gate every stream of one configuration group on its staged
         device frames ``images``; returns the group's result entries and
-        (``gated``) its stacked window keep grids."""
-        entries = []
-        keeps = []
-        pre = self._gate_batch(members, images, spec) if gated else {}
-        for row, (session, frame) in enumerate(members):
-            frame_idx = session.frame_idx
-            block = session.step(frame, precomputed=pre.get(row))
-            window = session.last_window_mask if session.gating else None
-            kept = int(window.sum()) if window is not None else h_o * w_o
-            entry = {
-                "stream_id": session.stream_id,
-                "frame_idx": frame_idx,
-                "block_mask": block,
-                "kept": kept,
-                "total": h_o * w_o,
-            }
-            if session.per_config:
-                entry["per_config"] = {
-                    st.name: (
-                        st.last_block_mask,
-                        int(st.last_window_mask.sum()),
-                        st.last_window_mask,
-                    )
-                    for st in session._states
-                }
-            tap = self.event_taps.get(session.stream_id)
-            if tap is not None:
-                # emit this tick's address-event packet from the gate
-                # state session.step() just wrote (same changed array the
-                # gate counted — the reconciliation contract)
-                entry["events"] = tap.observe_tick(frame_idx)
-            entries.append(entry)
-            if gated:
-                keeps.append(
-                    window
-                    if window is not None
-                    else np.ones((h_o, w_o), bool)
+        (``gated``) its window keep grids ``(n, h_o, w_o)``.
+
+        Under the ``gate`` span: ``gate_readback`` is the batched gate
+        dispatch and its grid read-back, ``gate_streams`` the one host pass
+        over the group's gate states (:func:`_step_gates`), the servos,
+        entries and keep grid."""
+        total = h_o * w_o
+        with telemetry.span("gate_readback"):
+            batch = self._gate_batch(members, images, spec) if gated else None
+        with telemetry.span("gate_streams"):
+            frame_idx = [session.frame_idx for session, _ in members]
+            blocks: list[np.ndarray | None] = [None] * len(members)
+            kept = [total] * len(members)
+            keep = None
+            if batch is not None:
+                rows, currents, deltas, has_delta, signed = batch
+                row_keep, windows, row_kept = _step_gates(
+                    spec, [members[i][0] for i in rows], deltas, has_delta,
+                    currents, signed,
                 )
-            self.stats.frames += 1
-            self.stats.windows_total += h_o * w_o
-            self.stats.windows_kept += kept
-        return entries, np.stack(keeps) if gated else None
+                for j, i in enumerate(rows):
+                    blocks[i] = row_keep[j]
+                    kept[i] = int(row_kept[j])
+                if len(rows) == len(members):
+                    keep = windows
+                else:
+                    keep = np.ones((len(members), h_o, w_o), bool)
+                    keep[rows] = windows
+            entries = []
+            for i, (session, frame) in enumerate(members):
+                if not session.gating:
+                    session.step(frame)
+                entry = {
+                    "stream_id": session.stream_id,
+                    "frame_idx": frame_idx[i],
+                    "block_mask": blocks[i],
+                    "kept": kept[i],
+                    "total": total,
+                }
+                if session.per_config:
+                    entry["per_config"] = {
+                        st.name: (
+                            st.last_block_mask,
+                            int(np.count_nonzero(st.last_window_mask)),
+                            st.last_window_mask,
+                        )
+                        for st in session._states
+                    }
+                tap = self.event_taps.get(session.stream_id)
+                if tap is not None:
+                    # emit this tick's address-event packet from the gate
+                    # state the group pass just wrote (same changed array
+                    # the gate counted — the reconciliation contract)
+                    entry["events"] = tap.observe_tick(frame_idx[i])
+                entries.append(entry)
+            self.stats.frames += len(members)
+            self.stats.windows_total += total * len(members)
+            self.stats.windows_kept += sum(kept)
+        return entries, keep
 
     def _gate_batch(
         self, members: list, images: jax.Array, spec: mapping.FPCASpec
-    ) -> dict[int, tuple]:
+    ) -> tuple[list[int], list, np.ndarray, np.ndarray, np.ndarray | None]:
         """Fleet-batched gate of a group's gated streams: ONE vmapped
         dispatch (bit-identical to the solo kernel) on the staged frames
         and the group's device-resident previous effective frames, so
@@ -998,8 +1030,11 @@ class StreamServer:
         The previous frames are the stack the last tick left when the same
         streams gate in the same order; otherwise (a first frame, a stream
         joined or left, a segment replaced a state) the stack is rebuilt
-        from each stream's own source.  Returns ``row -> precomputed`` for
-        :meth:`StreamSession.step`."""
+        from each stream's own source.  Returns, for :func:`_step_gates`,
+        the gated members' rows, where each one's new effective frame lives
+        (``(stack, j)``), the ``(n, bh, bw)`` |Δ| grids, which of them hold
+        a delta (a first frame's row, against the zero placeholder, does
+        not) and the signed grids (None without an event tap)."""
         rows = [i for i, (s, _) in enumerate(members) if s.gating]
         sessions = [members[i][0] for i in rows]
         n = len(rows)
@@ -1026,16 +1061,8 @@ class StreamServer:
             signed = None
         deltas = np.asarray(delta_d)
         self.stats.d2h_bytes += deltas.nbytes
-        # a stream's first frame has no delta: its grid row (against the
-        # zero placeholder) is dropped
-        return {
-            i: (
-                (stack, j),
-                None if srcs[j] is None else deltas[j],
-                None if srcs[j] is None or signed is None else signed[j],
-            )
-            for j, i in enumerate(rows)
-        }
+        has_delta = np.array([src is not None for src in srcs], bool)
+        return rows, [(stack, j) for j in range(n)], deltas, has_delta, signed
 
     def _restack(
         self, sessions: list[StreamSession], spec: mapping.FPCASpec

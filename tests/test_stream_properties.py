@@ -24,7 +24,7 @@ import math
 import numpy as np
 from _hypothesis_compat import given, settings, st
 
-from repro.core.mapping import FPCASpec, active_window_mask
+from repro.core.mapping import FPCASpec, active_window_mask, active_window_masks, output_dims
 from repro.kernels.fpca_conv.ops import StickyBucket, window_bucket
 from repro.serving.control import GateController, GateControllerConfig
 from repro.serving.streaming import DeltaGateConfig, StreamSession, block_delta_mask
@@ -74,7 +74,7 @@ def test_window_bucket_exact_at_pow2_boundaries(p, m_shift):
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(
     kernel=st.integers(3, 5),
     stride=st.integers(2, 5),
@@ -165,6 +165,52 @@ def test_gate_mask_feeds_active_window_mask(binning, seed):
     mask = session.step(frame)
     window = active_window_mask(spec, mask)     # raises on a shape mismatch
     assert window.all()                         # first frame = keyframe
+
+
+def _window_mask_by_loop(spec: FPCASpec, block_mask: np.ndarray) -> np.ndarray:
+    """Each window's (clipped) pixel footprint checked against the pixel
+    grid the block mask expands to, one window at a time."""
+    h_o, w_o = output_dims(spec)
+    b, n, s = spec.skip_block, spec.max_kernel, spec.stride
+    pixel = np.kron(block_mask, np.ones((b, b), bool))[: spec.eff_h, : spec.eff_w]
+    mask = np.zeros((h_o, w_o), bool)
+    for r in range(h_o):
+        for c in range(w_o):
+            mask[r, c] = pixel[r * s : r * s + n, c * s : c * s + n].any()
+    return mask
+
+
+@settings(max_examples=40, deadline=None)
+@given(
+    max_kernel=st.integers(1, 6), kernel=st.integers(1, 6), stride=st.integers(1, 6),
+    padding=st.integers(0, 3), binning=st.integers(1, 3), skip_block=st.integers(1, 9),
+    image_h=st.integers(6, 41), image_w=st.integers(6, 41),
+    density=st.floats(0.0, 1.0), seed=st.integers(0, 2**16),
+)
+def test_batched_window_masks_match_the_window_loop(
+    max_kernel, kernel, stride, padding, binning, skip_block, image_h, image_w,
+    density, seed,
+):
+    """``active_window_masks`` equals the per-window loop on every grid of a
+    stack, over specs with padding, binning, blocks that do not divide the
+    frame, stride below the kernel and kernel below ``max_kernel``; and
+    ``active_window_mask`` is row 0 of the batched call."""
+    kernel, stride = min(kernel, max_kernel), min(stride, max_kernel)
+    spec = FPCASpec(
+        image_h=image_h, image_w=image_w, out_channels=2, kernel=kernel,
+        stride=stride, max_kernel=max_kernel, padding=padding, binning=binning,
+        skip_block=skip_block,
+    )
+    if spec.eff_h < max_kernel or spec.eff_w < max_kernel:
+        return                              # no window fits the frame
+    rng = np.random.default_rng(seed)
+    grid = (math.ceil(spec.eff_h / skip_block), math.ceil(spec.eff_w / skip_block))
+    masks = rng.random((3, *grid)) < density
+    got = active_window_masks(spec, masks)
+    assert got.shape == (3, *output_dims(spec)) and got.dtype == bool
+    for j in range(3):
+        np.testing.assert_array_equal(got[j], _window_mask_by_loop(spec, masks[j]))
+    np.testing.assert_array_equal(active_window_mask(spec, masks[0]), got[0])
 
 
 # ---------------------------------------------------------------------------

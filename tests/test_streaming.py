@@ -21,6 +21,7 @@ Contracts pinned here:
 
 from __future__ import annotations
 
+import dataclasses
 import json
 
 import jax
@@ -906,31 +907,37 @@ class _HostRoundTripServer(StreamServer):
 
     def _gate_batch(self, members, images, spec):
         kernels = gating.host_gate_kernels(spec)
-        rows = [i for i, (s, _) in enumerate(members)
-                if s.gating and s._prev is not None]
-        if len(rows) > 1:
+        rows = [i for i, (s, _) in enumerate(members) if s.gating]
+        prior = [i for i in rows if members[i][0]._prev is not None]
+        if len(prior) > 1:
             curs, deltas = kernels.step_batch(
-                np.stack([members[i][0]._prev for i in rows]),
-                np.stack([members[i][1] for i in rows]),
+                np.stack([members[i][0]._prev for i in prior]),
+                np.stack([members[i][1] for i in prior]),
             )
-            pre = {i: (curs[j], deltas[j]) for j, i in enumerate(rows)}
+            pre = {i: (curs[j], deltas[j]) for j, i in enumerate(prior)}
         else:
             pre = {i: kernels.step(members[i][0]._prev, members[i][1])
-                   for i in rows}
-        out = {}
-        for i, (session, frame) in enumerate(members):
-            if not session.gating:
-                continue
+                   for i in prior}
+        none = np.zeros(gating.block_grid(spec), np.float32)
+        currents, deltas, signed = [], [], []
+        for i in rows:
+            session, frame = members[i]
             if i not in pre:
-                out[i] = (np.asarray(kernels.eff(frame)), None, None)
+                currents.append(np.asarray(kernels.eff(frame)))
+                deltas.append(none)
+                signed.append(none)
                 continue
             cur, delta = (np.asarray(a) for a in pre[i])
-            signed = (
+            currents.append(cur)
+            deltas.append(delta)
+            signed.append(
                 _block_reduce_mean(cur - session._prev, spec.skip_block)
-                if session.want_events else None
+                if session.want_events else none
             )
-            out[i] = (cur, delta, signed)
-        return out
+        events = any(members[i][0].want_events for i in rows)
+        return (rows, currents, np.stack(deltas),
+                np.array([i in pre for i in rows]),
+                np.stack(signed) if events else None)
 
 
 def _model_program(spec):
@@ -1137,3 +1144,245 @@ def test_streams_left_behind_keep_their_own_row(resident_pipe):
     np.testing.assert_array_equal(c1._prev, before)
     stack = server.sessions["c0"]._prev_src[0]
     assert [s.stream_id for s in stack.sessions] == ["c0", "c2"]
+
+
+# ---------------------------------------------------------------------------
+# the group gate pass: one host pass over a group's gate states
+# ---------------------------------------------------------------------------
+
+
+def _reference_window_mask(spec, keep):
+    """The window keep grid pixel by pixel: a window executes iff any pixel
+    of its (clipped) footprint lies in a kept block."""
+    h_o, w_o = output_dims(spec)
+    b, n, s = spec.skip_block, spec.max_kernel, spec.stride
+    pixel = np.kron(keep, np.ones((b, b), bool))[: spec.eff_h, : spec.eff_w]
+    return np.array([[pixel[r * s:r * s + n, c * s:c * s + n].any()
+                      for c in range(w_o)] for r in range(h_o)], bool).reshape(h_o, w_o)
+
+
+def _reference_gate_step(st, spec, delta, frame_idx):
+    """One gate state advanced alone by one frame (``delta`` None on the
+    first frame): the per-stream gate step the group pass replaced."""
+    if delta is not None:
+        changed = delta > np.float32(st.gate.threshold)
+        st.age = np.where(changed, 0, st.age + 1)
+        st.last_changed = changed
+        st.changed_total += int(changed.sum())
+    else:
+        st.last_changed = None
+    keyframe = delta is None or (
+        st.gate.keyframe_interval > 0
+        and frame_idx % st.gate.keyframe_interval == 0
+    )
+    keep = (np.ones_like(st.age, bool) if keyframe
+            else st.age <= st.gate.hysteresis)
+    st.last_keyframe = keyframe
+    st.last_block_mask = keep
+    st.block_masks.append(keep)
+    window = _reference_window_mask(spec, keep)
+    st.last_window_mask = window
+    if st.controller is not None:
+        obs = (float(window.mean())
+               if st.controller.config.metric == "keep" else None)
+        new_thr = st.controller.observe(keep, keyframe=keyframe, observation=obs)
+        if new_thr != st.gate.threshold:
+            st.gate = dataclasses.replace(st.gate, threshold=new_thr)
+    return keep
+
+
+class _PerStreamGateServer(StreamServer):
+    """The reference server: the same batched gate dispatch, then each
+    stream's gate states stepped one by one and the keep grids stacked."""
+
+    def _gate_group(self, members, images, spec, h_o, w_o, gated):
+        pre = {}
+        if gated:
+            rows, currents, deltas, has, signed = self._gate_batch(
+                members, images, spec)
+            pre = {i: (currents[j], deltas[j] if has[j] else None,
+                       signed[j] if signed is not None and has[j] else None)
+                   for j, i in enumerate(rows)}
+        entries, keeps = [], []
+        for row, (session, _) in enumerate(members):
+            frame_idx = session.frame_idx
+            block = window = None
+            if session.gating:
+                cur, delta, sgn = pre[row]
+                if session.want_events:
+                    session._last_signed = sgn
+                for st in session._states:
+                    keep = _reference_gate_step(st, spec, delta, frame_idx)
+                    block = keep if block is None else block | keep
+                    window = (st.last_window_mask if window is None
+                              else window | st.last_window_mask)
+                session._prev_src = cur
+                session.last_window_mask = window
+            session.frame_idx += 1
+            kept = int(window.sum()) if window is not None else h_o * w_o
+            entry = {"stream_id": session.stream_id, "frame_idx": frame_idx,
+                     "block_mask": block, "kept": kept, "total": h_o * w_o}
+            if session.per_config:
+                entry["per_config"] = {
+                    st.name: (st.last_block_mask, int(st.last_window_mask.sum()),
+                              st.last_window_mask)
+                    for st in session._states}
+            tap = self.event_taps.get(session.stream_id)
+            if tap is not None:
+                entry["events"] = tap.observe_tick(frame_idx)
+            entries.append(entry)
+            if gated:
+                keeps.append(window if window is not None
+                             else np.ones((h_o, w_o), bool))
+            self.stats.frames += 1
+            self.stats.windows_total += h_o * w_o
+            self.stats.windows_kept += kept
+        return entries, np.stack(keeps) if gated else None
+
+
+GROUP_GATE = DeltaGateConfig(threshold=0.02, hysteresis=1, keyframe_interval=3)
+_KEEP = GateControllerConfig(target=0.3, metric="keep")
+_ENERGY = GateControllerConfig(target=0.4, metric="energy")
+
+
+def _group_pass_scenario(name):
+    """``(fleet, steps)``: whether a FleetController drives the server, and
+    ``("add", sid, configs, kwargs)`` joins, ``("tick", sids)`` ticks (each
+    stream's next frame of its own moving scene) and ``("segment", sid, k)``
+    compiled segments."""
+    one = {}
+    per_config = {"gate": {
+        "A": DeltaGateConfig(threshold=0.01, hysteresis=1, keyframe_interval=4),
+        "B": DeltaGateConfig(threshold=0.08, hysteresis=0, keyframe_interval=0)}}
+    if name == "churn":        # first frames, joins, leaves, keyframe ticks
+        return False, [
+            ("add", "c0", "cls", one), ("add", "c1", "cls", one),
+            ("tick", ("c0", "c1")), ("tick", ("c0", "c1")),
+            ("add", "c2", "cls", one), ("tick", ("c0", "c1", "c2")),
+            ("tick", ("c0", "c2")), ("tick", ("c2", "c0")),
+            ("tick", ("c0", "c1", "c2")), ("tick", ("c0", "c1", "c2"))]
+    if name == "per_config":   # per-config thresholds beside shared and dense
+        return False, [
+            ("add", "c0", ("A", "B"), per_config), ("add", "c1", ("A", "B"), one),
+            ("add", "c2", ("A", "B"), {"gate": None}),
+            *[("tick", ("c0", "c1", "c2"))] * 6]
+    if name == "servo":        # keep and energy servos under a fleet retarget
+        return True, [
+            ("add", "c0", "cls", {"controller": _KEEP}),
+            ("add", "c1", "cls", {"controller": _ENERGY}),
+            ("add", "c2", ("A", "B"), {**per_config, "controller": {
+                "A": _KEEP, "B": _ENERGY}}),
+            *[("tick", ("c0", "c1", "c2"))] * 7]
+    if name == "events":       # an event tap in a batched group
+        return False, [
+            ("add", "c0", "cls", {"events": True}), ("add", "c1", "cls", one),
+            *[("tick", ("c0", "c1"))] * 3, ("tick", ("c0",)),
+            *[("tick", ("c0", "c1"))] * 2]
+    if name == "interleave":   # per-tick -> segment -> per-tick
+        return False, [
+            ("add", "c0", "cls", {"events": True}), ("add", "c1", "cls", one),
+            *[("tick", ("c0", "c1"))] * 3, ("segment", "c0", 3),
+            *[("tick", ("c0", "c1"))] * 3]
+    raise ValueError(name)
+
+
+def _gate_snapshot(server):
+    """Every gate state's host decision state, copied."""
+    snap = {}
+    for sid, session in server.sessions.items():
+        win = session.last_window_mask
+        snap[sid] = [session.frame_idx, None if win is None else win.copy()]
+        for st in session._states:
+            snap[sid].append((
+                st.name, st.age.copy(), st.changed_total, st.last_keyframe,
+                st.gate, None if st.last_changed is None else st.last_changed.copy(),
+                st.last_block_mask.copy(), st.last_window_mask.copy(),
+                [m.copy() for m in st.block_masks],
+                None if st.controller is None else (
+                    st.controller.threshold, st.controller.ema,
+                    st.controller.config.target),
+            ))
+    return snap
+
+
+def _serve_group_pass(server_cls, pipe, name):
+    from repro.serving.fleet import FleetConfig, FleetController
+
+    use_fleet, steps = _group_pass_scenario(name)
+    server = server_cls(pipe, GROUP_GATE)
+    fleet = FleetController(server, FleetConfig(rebalance_ticks=2)) if use_fleet else None
+    cams, sent, out, snaps = {}, {}, [], []
+
+    def frame(sid):
+        sent[sid] += 1
+        return cams[sid].frame_at(sent[sid] - 1)
+
+    for step in steps:
+        if step[0] == "add":
+            _, sid, configs, kw = step
+            (fleet or server).add_stream(sid, configs, **kw)
+            cams[sid] = SyntheticMovingObject((H, W), seed=80 + len(cams), radius=4.0)
+            sent[sid] = 0
+            continue
+        if step[0] == "tick":
+            out += [r for results in (fleet or server).run([{sid: frame(sid) for sid in step[1]}])
+                    for r in results]
+        else:
+            _, sid, k = step
+            out += server.run_segment(sid, np.stack([frame(sid) for _ in range(k)]))
+        snaps.append(_gate_snapshot(server))
+    allocations = None if fleet is None else (
+        fleet.rebalances, {m.stream_id: m.allocation for m in fleet._members.values()})
+    return server, out, snaps, allocations
+
+
+def _assert_same(a, b, where):
+    if isinstance(a, np.ndarray) or isinstance(b, np.ndarray):
+        assert isinstance(a, np.ndarray) and isinstance(b, np.ndarray), where
+        assert a.dtype == b.dtype, where
+        np.testing.assert_array_equal(a, b, err_msg=where)
+    elif isinstance(a, (list, tuple)):
+        assert type(a) is type(b) and len(a) == len(b), where
+        for i, (x, y) in enumerate(zip(a, b)):
+            _assert_same(x, y, f"{where}[{i}]")
+    else:
+        assert a == b, where
+
+
+@pytest.mark.parametrize("name", ["churn", "per_config", "servo", "events", "interleave"])
+def test_group_gate_pass_matches_per_stream_steps(resident_pipe, name):
+    """The one host pass over a group's gate states leaves every state as
+    the per-stream step would, tick for tick: ages, keep and window grids,
+    changed counts, mask history, keyframes, thresholds and servos; and
+    the results, event packets and fleet allocations are the same."""
+    from repro.serving.observe import assert_reconciled
+
+    server, got, got_snaps, got_alloc = _serve_group_pass(StreamServer, resident_pipe, name)
+    _, want, want_snaps, want_alloc = _serve_group_pass(
+        _PerStreamGateServer, resident_pipe, name)
+    assert len(got_snaps) == len(want_snaps)
+    for t, (a, b) in enumerate(zip(got_snaps, want_snaps)):
+        assert a.keys() == b.keys()
+        for sid in a:
+            _assert_same(a[sid], b[sid], f"tick {t} stream {sid}")
+    assert len(got) == len(want) > 0
+    for a, b in zip(got, want):
+        for field in ("stream_id", "frame_idx", "config", "kept_windows",
+                      "total_windows", "block_mask", "counts", "logits"):
+            _assert_same(getattr(a, field), getattr(b, field), field)
+        assert (a.events is None) == (b.events is None)
+        if a.events is not None:
+            assert a.events.coords.tolist() == b.events.coords.tolist()
+            assert a.events.polarity.tolist() == b.events.polarity.tolist()
+    assert got_alloc == want_alloc
+    assert any(0 < r.kept_windows < r.total_windows for r in got)
+    # a keyframe tick past a stream's first frame
+    assert any(state[3] for snap in got_snaps for row in snap.values()
+               if row[0] > 1 for state in row[2:])
+    assert_reconciled(resident_pipe, server)
+    if name == "servo":
+        assert got_alloc[0] > 0
+        assert any(st.gate.threshold != GROUP_GATE.threshold
+                   for s in server.sessions.values() for st in s._states)
+    if name in ("events", "interleave"):
+        assert server.event_taps["c0"].stats.events > 0

@@ -173,6 +173,25 @@ def test_phase_spans_nest_under_serve_tick(fleet_spans):
             assert s["t0_ns"] + int(s["dur_s"] * 1e9) <= hi
 
 
+def test_gate_sub_spans_nest_under_gate(fleet_spans):
+    """gate_readback (the batched gate dispatch and its read-back), then
+    gate_streams (the host pass over the group's gate states), run once
+    per tick inside that tick's gate span."""
+    _, _, spans = fleet_spans
+    gates = [s for s in spans if s["span"] == "gate"]
+    assert len(gates) == N_TICKS
+    for gate in gates:
+        lo = gate["t0_ns"]
+        hi = lo + int(gate["dur_s"] * 1e9) + 1
+        inside = [s for s in spans if s["span"].startswith("gate_")
+                  and lo <= s["t0_ns"] <= hi]
+        assert [s["span"] for s in inside] == ["gate_readback", "gate_streams"]
+        assert inside[0]["t0_ns"] + int(inside[0]["dur_s"] * 1e9) <= inside[1]["t0_ns"] + 1
+        for s in inside:
+            assert s["parent"] == "gate" and s["depth"] == 2
+            assert s["t0_ns"] + int(s["dur_s"] * 1e9) <= hi
+
+
 def test_realise_follows_its_tick_at_depth_two(fleet_spans):
     """One realise per tick with the tick's id, starting after that tick's
     serve_tick ends and after tick + depth was dispatched (the depth-2
@@ -200,7 +219,8 @@ def test_byte_counters_match_shapes(on, fleet_spans):
         server = fleet_spans[0]
     else:
         server = _serve_model_fleet()[0]
-        for name in PHASES + ("serve_tick", "realise"):
+        for name in PHASES + ("serve_tick", "realise", "gate_readback",
+                              "gate_streams"):
             assert telemetry.span(name) is telemetry._NULL_SPAN
     assert (server.stats.h2d_bytes, server.stats.d2h_bytes) == _reckoned_bytes(server)
     assert_reconciled(server.pipeline, server)
