@@ -41,7 +41,6 @@ import numpy as np
 
 from repro.core import gating, mapping
 from repro.fpca import telemetry
-from repro.serving.streaming import _block_reduce_mean
 
 __all__ = ["EventPacket", "EventStats", "EventTap", "segment_events"]
 
@@ -205,9 +204,11 @@ def segment_events(
     ``frames`` are the segment's served ticks (``(ticks, H, W, c_i)``);
     ``prev_eff`` the effective frame carried *into* the segment (``None``
     at stream start); ``threshold`` the gate threshold the segment traced
-    (captured *before* the boundary servo actuates).  Uses the same jitted
-    :mod:`repro.core.gating` kernels the in-scan gate inlines, so the
-    changed-block decisions are bit-identical to what the device computed.
+    (captured *before* the boundary servo actuates).  Each tick is one
+    :meth:`~repro.core.gating.HostGateKernels.step_signed` dispatch, the
+    jitted :mod:`repro.core.gating` kernel whose vmapped twin the per-tick
+    gate runs, so the changed-block decisions are bit-identical to what the
+    device computed.
     """
     kernels = gating.host_gate_kernels(spec)
     grid_shape = gating.block_grid(spec)
@@ -215,13 +216,13 @@ def segment_events(
     prev = None if prev_eff is None else np.asarray(prev_eff, np.float32)
     packets: list[EventPacket] = []
     for t, frame in enumerate(np.asarray(frames, np.float32)):
-        cur = np.asarray(kernels.eff(frame))
         if prev is None:
+            cur = kernels.eff(frame)
             changed = signed = None
         else:
-            delta = np.asarray(kernels.delta(prev, cur))
-            changed = delta > np.float32(threshold)
-            signed = _block_reduce_mean(cur - prev, block)
+            cur, delta, signed_d = kernels.step_signed(prev, frame)
+            changed = np.asarray(delta) > np.float32(threshold)
+            signed = np.asarray(signed_d)
         packets.append(
             _packet(stream_id, first_frame_idx + t, changed, signed,
                     grid_shape, block)

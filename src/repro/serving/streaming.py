@@ -100,38 +100,14 @@ __all__ = [
 _USE_SERVER = object()   # add_stream sentinel: "inherit the server default"
 
 
-def _effective_frame(frame: np.ndarray, spec: mapping.FPCASpec) -> np.ndarray:
-    """Frame as the pixel array sees it: binned (average pool) grayscale.
-
-    Evaluated through the jitted :mod:`repro.core.gating` kernel — the SAME
-    jnp numerics the device-compiled segment executor inlines into its scan —
-    so host and device gate decisions compare identical float32 bits (the
-    segment parity contract)."""
-    kernels = gating.host_gate_kernels(spec)
-    return np.asarray(kernels.eff(np.asarray(frame, np.float32)))
-
-
-def _block_reduce_mean(x: np.ndarray, block: int) -> np.ndarray:
-    """Mean over ``block x block`` tiles (ragged edge tiles average their
-    real pixels only), shape ``(ceil(h/b), ceil(w/b))``."""
-    h, w = x.shape
-    bh, bw = math.ceil(h / block), math.ceil(w / block)
-    padded = np.zeros((bh * block, bw * block), x.dtype)
-    padded[:h, :w] = x
-    sums = padded.reshape(bh, block, bw, block).sum((1, 3))
-    ones = np.zeros((bh * block, bw * block), np.float32)
-    ones[:h, :w] = 1.0
-    counts = ones.reshape(bh, block, bw, block).sum((1, 3))
-    return sums / counts
-
-
 def block_delta(
     prev_eff: np.ndarray, cur_eff: np.ndarray, spec: mapping.FPCASpec
 ) -> np.ndarray:
     """Mean absolute per-block change between two *effective* (binned)
     frames — the statistic every per-config threshold compares against.
-    Jitted :mod:`repro.core.gating` numerics, bit-shared with the in-scan
-    gate (see :func:`_effective_frame`)."""
+    Jitted :mod:`repro.core.gating` numerics, the SAME jnp numerics the
+    device-compiled segment executor inlines into its scan, so host and
+    device gate decisions compare identical float32 bits."""
     kernels = gating.host_gate_kernels(spec)
     return np.asarray(
         kernels.delta(
@@ -195,16 +171,14 @@ def _step_gates(
     sessions: Sequence["StreamSession"],
     deltas: np.ndarray,
     has_delta: np.ndarray,
-    currents: Sequence[Any],
     signed: np.ndarray | None,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Advance the delta gates of gated ``sessions`` (all on ``spec``) by
     one frame in one host pass.
 
     ``deltas[j]`` is session ``j``'s block |Δ| grid, read where
-    ``has_delta[j]`` (False on a stream's first frame); ``currents[j]`` is
-    where its new effective frame lives and ``signed[j]`` its signed
-    block-mean Δ (None when no session wants events).
+    ``has_delta[j]`` (False on a stream's first frame), and ``signed[j]``
+    its signed block-mean Δ (None when no session wants events).
 
     Each gate state (one per session, or one per config of a per-config
     session) is one row of ``(rows, bh, bw)`` arrays: the float32 threshold
@@ -269,43 +243,143 @@ def _step_gates(
             session._last_signed = (
                 signed[j] if signed is not None and has_delta[j] else None
             )
-        session._prev_src = currents[j]
         session.frame_idx += 1
         session.last_window_mask = windows[j]
     return keep, windows, np.count_nonzero(windows, axis=(1, 2))
 
 
-class _PrevStack:
-    """The previous effective frames of one group's gated streams, stacked
-    on the device as ``(n, eff_h, eff_w)``, row ``j`` for ``sessions[j]``.
-    A stream's previous frame is that row while its session's
-    ``_prev_src`` is ``(stack, j)``; each tick's batched gate step replaces
-    ``prev`` with the new stack."""
+class _GroupState:
+    """Every per-stream device state of one stream group, row ``j`` for
+    ``sessions[j]``: ``prev``, the ``(n, eff_h, eff_w)`` float32 previous
+    effective frames the delta gate compares against, and ``eff``, per
+    model config the ``(n, h_o, w_o, C)`` effective activation maps the
+    skip-aware head patches.  A session that has served a frame points at
+    its row as ``session._row == (state, j)``; each tick's gate and head
+    replace ``prev`` and ``eff[name]`` whole.  :func:`_regroup` builds
+    one."""
 
-    __slots__ = ("sessions", "prev")
+    __slots__ = ("sessions", "prev", "eff")
 
-    def __init__(self, sessions: list, prev: jax.Array):
+    def __init__(self, sessions: list, prev: jax.Array, eff: dict):
         self.sessions = sessions
         self.prev = prev
+        self.eff = eff
 
-    def holds(self, sessions: list) -> bool:
-        """True when ``sessions``, in this order, are exactly the streams
-        whose previous frames are this stack's rows."""
-        return len(sessions) == len(self.sessions) and all(
-            s is t and isinstance(s._prev_src, tuple)
-            and s._prev_src[0] is self and s._prev_src[1] == j
-            for j, (s, t) in enumerate(zip(sessions, self.sessions))
+
+def _regroup(
+    sessions: Sequence["StreamSession"],
+    sources: Sequence[tuple | None] | None = None,
+    *,
+    release: bool = True,
+) -> _GroupState:
+    """Build one state for ``sessions`` and point each at its row.
+
+    Row ``j`` comes from ``sources[j]`` (default: where ``sessions[j]``
+    points now), a ``(state, row)``, or None for a stream that has served
+    no frame.  Each field is one take from the sources' stacks behind a
+    zero row, which a missing source, or a source state without a map for
+    that model config, reads.  With ``release``, every stream that still
+    reads a row of a source state but is not in ``sessions`` gets a
+    one-row state of its own the same way, so none pins a stack its group
+    has left."""
+    if sources is None:
+        sources = [s._row for s in sessions]
+    olds = list({id(s[0]): s[0] for s in sources if s is not None}.values())
+    eff_shapes = {
+        name: a.shape[1:] for old in olds for name, a in old.eff.items()
+    }
+
+    def take(pick, shape):
+        pools, base, at = [jnp.zeros((1, *shape), jnp.float32)], {}, 1
+        for old in olds:
+            a = pick(old)
+            if a is not None:
+                pools.append(a)
+                base[id(old)] = at
+                at += a.shape[0]
+        idx = [
+            0 if src is None or id(src[0]) not in base
+            else base[id(src[0])] + src[1]
+            for src in sources
+        ]
+        return jnp.concatenate(pools)[np.asarray(idx)]
+
+    spec = sessions[0].spec
+    state = _GroupState(
+        list(sessions),
+        take(lambda old: old.prev, (spec.eff_h, spec.eff_w)),
+        {
+            name: take(lambda old, name=name: old.eff.get(name), shape)
+            for name, shape in eff_shapes.items()
+        },
+    )
+    for j, s in enumerate(sessions):
+        s._row = (state, j)
+    if release:
+        for old in olds:
+            for i, t in enumerate(old.sessions):
+                if t._row == (old, i):
+                    _regroup([t], release=False)
+    return state
+
+
+def _state_of(
+    sessions: Sequence["StreamSession"], stats: "StreamStats | None"
+) -> _GroupState:
+    """The state whose rows are exactly ``sessions``, in order: the one
+    they all point at, as the last tick left it, or else one
+    :func:`_regroup` builds.  ``stats`` (None: not counted) counts the
+    gated rows as served resident or restacked."""
+    row = sessions[0]._row
+    state = None if row is None else row[0]
+    resident = (
+        state is not None
+        and len(state.sessions) == len(sessions)
+        and all(s._row == (state, j) for j, s in enumerate(sessions))
+    )
+    if not resident:
+        state = _regroup(sessions)
+    if stats is not None:
+        n = sum(s.gating for s in sessions)
+        if resident:
+            stats.gate_rows_resident += n
+        else:
+            stats.gate_rows_restacked += n
+    return state
+
+
+def _gate_rows(
+    state: _GroupState,
+    rows: Sequence[int],
+    frames: jax.Array,
+    stats: "StreamStats | None",
+) -> tuple[np.ndarray, np.ndarray | None]:
+    """One vmapped gate dispatch (bit-identical to the solo kernel) for
+    rows ``rows`` of ``state`` on their device ``frames``: the new
+    effective frames replace those rows of ``state.prev`` on the device,
+    so pixels never pass through the host, and only the ``(len(rows), bh,
+    bw)`` block |Δ| grids come back, with the signed grids when one of the
+    streams has an event tap (else None).  When every row is gated
+    ``state.prev`` is stepped whole; a mixed group takes and scatters its
+    gated rows.  ``stats`` (None: not billed) counts the bytes read
+    back."""
+    kernels = gating.host_gate_kernels(state.sessions[0].spec)
+    whole = len(rows) == len(state.sessions)
+    idx = None if whole else np.asarray(rows)
+    prev = state.prev if whole else state.prev[idx]
+    if any(state.sessions[i].want_events for i in rows):
+        new, delta_d, signed_d = kernels.step_batch_signed(prev, frames)
+        signed = np.asarray(signed_d)
+    else:
+        new, delta_d = kernels.step_batch(prev, frames)
+        signed = None
+    state.prev = new if whole else state.prev.at[idx].set(new)
+    deltas = np.asarray(delta_d)
+    if stats is not None:
+        stats.d2h_bytes += deltas.nbytes + (
+            0 if signed is None else signed.nbytes
         )
-
-    def release(self, keep: list) -> None:
-        """Give each stream still reading a row of this stack, other than
-        ``keep``, its own copy of the row, so no stream pins a stack its
-        group has left behind."""
-        kept = {id(s) for s in keep}
-        for j, s in enumerate(self.sessions):
-            src = s._prev_src
-            if id(s) not in kept and isinstance(src, tuple) and src[0] is self:
-                s._prev_src = self.prev[j]
+    return deltas, signed
 
 
 class StreamSession:
@@ -326,9 +400,11 @@ class StreamSession:
     ``stats`` (the owning server's :class:`StreamStats`) is billed the
     host↔device bytes of the gate dispatches this session makes itself.
 
-    The previous effective frame stays on the device between ticks (a row
-    of its group's stacked array under :class:`StreamServer`, its own
-    array when stepped alone); :attr:`_prev` reads it back on demand.
+    Its device state (the previous effective frame and, per model config,
+    the effective activation map) is a row of a :class:`_GroupState`:
+    its group's under :class:`StreamServer`, a one-row state of its own
+    when stepped alone or served by a segment.  :attr:`_prev` reads the
+    frame back on demand.
     """
 
     def __init__(
@@ -353,17 +429,12 @@ class StreamSession:
             controller, Mapping
         )
         self.frame_idx = 0
-        # where the previous effective frame lives: None before the first
-        # frame, a host array (seeded by absorb_segment), this session's own
-        # device array (stepped alone) or ``(stack, row)`` of a _PrevStack
-        self._prev_src: Any = None
+        # (state, row) of the _GroupState holding this stream's device
+        # state; None before the first frame
+        self._row: tuple[_GroupState, int] | None = None
         bh = math.ceil(spec.eff_h / spec.skip_block)
         bw = math.ceil(spec.eff_w / spec.skip_block)
         self.last_window_mask: np.ndarray | None = None
-        # per-config effective activation map (model configs only): the
-        # running frontend output with each tick's kept windows patched in —
-        # what the skip-aware digital head classifies
-        self._eff: dict[str, Any] = {}
         # device-resident carry threaded between compiled segment launches
         # (None until the stream first serves a segment)
         self._segment_state: Any | None = None
@@ -456,19 +527,13 @@ class StreamSession:
 
     @property
     def _prev(self) -> np.ndarray | None:
-        """The previous effective frame, read back to the host (None before
-        the first frame)."""
-        prev = self._prev_value()
-        return None if prev is None else np.asarray(prev, np.float32)
-
-    def _prev_value(self) -> Any:
-        """The previous effective frame where it lives: a device array (a
-        row of a group's stack is sliced out), a host array, or None."""
-        src = self._prev_src
-        if isinstance(src, tuple):
-            stack, row = src
-            return stack.prev[row]
-        return src
+        """The previous effective frame, read back to the host from this
+        stream's row (None before the first frame, and for a dense
+        stream)."""
+        if self._row is None or not self.gating:
+            return None
+        state, j = self._row
+        return np.asarray(state.prev[j], np.float32)
 
     def step(self, frame: Any) -> np.ndarray | None:
         """Advance one frame, gated alone; returns the block keep mask
@@ -481,64 +546,32 @@ class StreamSession:
         fused call must execute; each config's own decision is on its
         :meth:`state_for` entry.
 
-        The frame (a host or device array) goes to the device, the
-        effective frame stays there, and only the block |Δ| grid (and the
-        signed grid for :attr:`want_events`) comes back; the gate decision
-        is :func:`_step_gates` on this one stream, the pass
-        :class:`StreamServer` runs over a whole group.
+        The frame (a host or device array) goes to the device and is gated
+        against this stream's one-row state (:func:`_gate_rows`, the
+        dispatch :class:`StreamServer` makes for a whole group; a first
+        frame only stores its effective frame there); only the block |Δ|
+        grid (and the signed grid for :attr:`want_events`) comes back, and
+        the gate decision is :func:`_step_gates` on this one stream.
         """
         if not self.gating:
             self.frame_idx += 1
             return None
-        cur, delta_blocks, signed = self._gate_alone(frame)
-        if delta_blocks is None:
-            delta_blocks = np.zeros(self._primary.age.shape, np.float32)
-            has_delta = np.zeros(1, bool)
-        else:
-            has_delta = np.ones(1, bool)
-        keep, _, _ = _step_gates(
-            self.spec, [self], delta_blocks[None], has_delta, [cur],
-            None if signed is None else signed[None],
-        )
-        return keep[0]
-
-    def _gate_alone(self, frame: Any) -> tuple:
-        """One gate dispatch for this stream alone (ONE fused kernel per
-        tick: the gate result is needed synchronously to build this tick's
-        window mask).  Returns ``(effective frame on the device, |Δ| grid,
-        signed grid)``, the grids None on the first frame."""
-        kernels = gating.host_gate_kernels(self.spec)
         if not isinstance(frame, jax.Array):
             frame = np.asarray(frame, np.float32)
-            self._bill(frame.nbytes, 0)
-        prev = self._prev_value()
-        self._count(resident=isinstance(self._prev_src, jax.Array))
-        if prev is None:
-            return kernels.eff(frame), None, None
-        if isinstance(prev, np.ndarray):
-            self._bill(prev.nbytes, 0)
-        if self.want_events:
-            cur, delta_d, signed_d = kernels.step_signed(prev, frame)
-            signed = np.asarray(signed_d)
+            if self.stats is not None:
+                self.stats.h2d_bytes += frame.nbytes
+        frame = jnp.asarray(frame)
+        has_delta = np.array([self.frame_idx > 0])
+        state = _state_of([self], self.stats)
+        if has_delta[0]:
+            deltas, signed = _gate_rows(state, [0], frame[None], self.stats)
         else:
-            cur, delta_d = kernels.step(prev, frame)
+            # a first frame has nothing to compare: keep its effective frame
+            state.prev = gating.host_gate_kernels(self.spec).eff(frame)[None]
+            deltas = np.zeros((1, *self._primary.age.shape), np.float32)
             signed = None
-        delta_blocks = np.asarray(delta_d)
-        self._bill(0, delta_blocks.nbytes + (
-            0 if signed is None else signed.nbytes))
-        return cur, delta_blocks, signed
-
-    def _bill(self, h2d: int, d2h: int) -> None:
-        if self.stats is not None:
-            self.stats.h2d_bytes += h2d
-            self.stats.d2h_bytes += d2h
-
-    def _count(self, resident: bool) -> None:
-        if self.stats is not None:
-            if resident:
-                self.stats.gate_rows_resident += 1
-            else:
-                self.stats.gate_rows_restacked += 1
+        keep, _, _ = _step_gates(self.spec, [self], deltas, has_delta, signed)
+        return keep[0]
 
     def absorb_segment(self, seg) -> None:
         """Fold one finished device-compiled segment into this session.
@@ -552,13 +585,18 @@ class StreamSession:
         :meth:`GateController.converged_tick` audits cover the in-segment
         ticks as if they had been served one by one.  The servo applies ONE
         bounded actuation at the boundary
-        (:meth:`GateController.observe_segment`).
+        (:meth:`GateController.observe_segment`).  The segment's device
+        carry (previous effective frame and, for a model, effective map)
+        becomes this stream's one-row state, without a host copy.
         """
         if self.per_config:
             raise NotImplementedError(
                 "compiled segments serve one gate per stream; per-config "
                 "fan-out streams must use per-tick serving"
             )
+        carry = seg.state
+        eff = {} if carry.eff is None else {self.config: carry.eff[None]}
+        _regroup([self], [(_GroupState([self], carry.prev_eff[None], eff), 0)])
         ticks = seg.ticks
         if not seg.gated or not self.gating:
             if seg.gated != self.gating:
@@ -579,7 +617,6 @@ class StreamSession:
             st.last_window_mask = window
             self.last_window_mask = window
         st.age = np.asarray(seg.state.age, np.int64)
-        self._prev_src = np.asarray(seg.state.prev_eff, np.float32)
         self.frame_idx = int(seg.state.frame_idx)
         if st.controller is not None and ticks:
             obs = None
@@ -682,11 +719,11 @@ class StreamStats(telemetry.StatsView):
     enters a device call (gate inputs, frames, keep grids) and of every
     device array it realises on the host (gate results, counts, logits).
     ``gate_rows_resident`` / ``gate_rows_restacked`` split the gated
-    stream-ticks by where the previous effective frame came from: the
-    device-resident stack of the stream's group as the last tick left it
-    (or the stream's own device array, gated alone), or state that had to
-    be rebuilt first (a first frame, a change of the group's members or
-    their order, a state replaced by a segment).
+    stream-ticks by where their device state came from: the group's
+    :class:`_GroupState` as the last tick left it (a one-row state of the
+    stream's own, gated alone), or one rebuilt first (a first frame, a
+    change of the group's members or their order, a state replaced by a
+    segment).
 
     The server deliberately does NOT parent-chain into the pipeline's
     stats: it is a scoped observer of a *shared* pipeline (other callers
@@ -749,7 +786,6 @@ class StreamServer:
         depth: int = 2,
         gating: bool = True,
         controller: GateControllerConfig | None = None,
-        fuse_shared_heads: bool = True,
     ):
         if depth < 1:
             raise ValueError("depth must be >= 1")
@@ -757,12 +793,6 @@ class StreamServer:
         self.gate = gate if gating else None
         self.controller = controller if gating else None
         self.depth = depth
-        # when several model configs of one fused launch bind the SAME model
-        # signature (zoo archs sharing a head, A/B weight variants), run ONE
-        # vmapped head pass over all (config, stream) rows instead of one
-        # call per config; bit-identical to the per-config path (pinned in
-        # tests) because the patched-head math is row-independent
-        self.fuse_shared_heads = fuse_shared_heads
         self.sessions: dict[str, StreamSession] = {}
         self.event_taps: dict[str, Any] = {}
         self.stats = StreamStats()
@@ -943,7 +973,7 @@ class StreamServer:
             )
             launch = {"counts": counts, "entries": entries, "slices": slices}
             with telemetry.span("head"):
-                self._model_head_pass(launch, members, h_o, w_o)
+                self._model_head_pass(launch, members, keep, h_o, w_o)
             launches.append(launch)
         self.stats.bucket_switches += pstats.bucket_switches - before[0]
         self.stats.bucket_shrinks_deferred += pstats.bucket_shrinks_deferred - before[1]
@@ -959,12 +989,13 @@ class StreamServer:
         device frames ``images``; returns the group's result entries and
         (``gated``) its window keep grids ``(n, h_o, w_o)``.
 
-        Under the ``gate`` span: ``gate_readback`` is the batched gate
-        dispatch and its grid read-back, ``gate_streams`` the one host pass
-        over the group's gate states (:func:`_step_gates`), the servos,
-        entries and keep grid."""
+        Under the ``gate`` span: ``gate_readback`` is the group's device
+        state (:func:`_state_of`), the batched gate dispatch and its grid
+        read-back, ``gate_streams`` the one host pass over the group's gate
+        states (:func:`_step_gates`), the servos, entries and keep grid."""
         total = h_o * w_o
         with telemetry.span("gate_readback"):
+            _state_of([session for session, _ in members], self.stats)
             batch = self._gate_batch(members, images, spec) if gated else None
         with telemetry.span("gate_streams"):
             frame_idx = [session.frame_idx for session, _ in members]
@@ -972,10 +1003,10 @@ class StreamServer:
             kept = [total] * len(members)
             keep = None
             if batch is not None:
-                rows, currents, deltas, has_delta, signed = batch
+                rows, deltas, has_delta, signed = batch
                 row_keep, windows, row_kept = _step_gates(
                     spec, [members[i][0] for i in rows], deltas, has_delta,
-                    currents, signed,
+                    signed,
                 )
                 for j, i in enumerate(rows):
                     blocks[i] = row_keep[j]
@@ -1019,161 +1050,100 @@ class StreamServer:
 
     def _gate_batch(
         self, members: list, images: jax.Array, spec: mapping.FPCASpec
-    ) -> tuple[list[int], list, np.ndarray, np.ndarray, np.ndarray | None]:
-        """Fleet-batched gate of a group's gated streams: ONE vmapped
-        dispatch (bit-identical to the solo kernel) on the staged frames
-        and the group's device-resident previous effective frames, so
-        pixels never pass through the host and the per-tick host cost
-        stays flat as the fleet grows.  Only the block |Δ| grid (and, when
-        a member has an event tap, the signed grid) comes back.
-
-        The previous frames are the stack the last tick left when the same
-        streams gate in the same order; otherwise (a first frame, a stream
-        joined or left, a segment replaced a state) the stack is rebuilt
-        from each stream's own source.  Returns, for :func:`_step_gates`,
-        the gated members' rows, where each one's new effective frame lives
-        (``(stack, j)``), the ``(n, bh, bw)`` |Δ| grids, which of them hold
-        a delta (a first frame's row, against the zero placeholder, does
-        not) and the signed grids (None without an event tap)."""
+    ) -> tuple[list[int], np.ndarray, np.ndarray, np.ndarray | None]:
+        """Fleet-batched gate of a group's gated streams: ONE dispatch on
+        the staged frames and the group state's previous effective frames
+        (:func:`_gate_rows`), so the per-tick host cost stays flat as the
+        fleet grows.  Returns, for :func:`_step_gates`, the gated members'
+        rows, the ``(n, bh, bw)`` |Δ| grids, which of them hold a delta (a
+        first frame's row, against the zero placeholder, does not) and the
+        signed grids (None without an event tap)."""
         rows = [i for i, (s, _) in enumerate(members) if s.gating]
-        sessions = [members[i][0] for i in rows]
-        n = len(rows)
-        srcs = [s._prev_src for s in sessions]
-        stack = srcs[0][0] if isinstance(srcs[0], tuple) else None
-        if stack is not None and stack.holds(sessions):
-            self.stats.gate_rows_resident += n
-        else:
-            prev = self._restack(sessions, spec)
-            for old in {src[0] for src in srcs if isinstance(src, tuple)}:
-                old.release(sessions)
-            stack = _PrevStack(sessions, prev)
-            self.stats.gate_rows_restacked += n
-        frames = images if n == len(members) else images[np.asarray(rows)]
-        kernels = gating.host_gate_kernels(spec)
-        if any(s.want_events for s in sessions):
-            stack.prev, delta_d, signed_d = kernels.step_batch_signed(
-                stack.prev, frames
-            )
-            signed = np.asarray(signed_d)
-            self.stats.d2h_bytes += signed.nbytes
-        else:
-            stack.prev, delta_d = kernels.step_batch(stack.prev, frames)
-            signed = None
-        deltas = np.asarray(delta_d)
-        self.stats.d2h_bytes += deltas.nbytes
-        has_delta = np.array([src is not None for src in srcs], bool)
-        return rows, [(stack, j) for j in range(n)], deltas, has_delta, signed
-
-    def _restack(
-        self, sessions: list[StreamSession], spec: mapping.FPCASpec
-    ) -> jax.Array:
-        """Stack the sessions' previous effective frames on the device, a
-        zero placeholder for a stream with none yet; host arrays (a state
-        a segment replaced) are copied up."""
-        prevs = [s._prev_value() for s in sessions]
-        if all(p is None for p in prevs):
-            return jnp.zeros((len(prevs), spec.eff_h, spec.eff_w), jnp.float32)
-        zero = jnp.zeros((spec.eff_h, spec.eff_w), jnp.float32)
-        self.stats.h2d_bytes += sum(
-            p.nbytes for p in prevs if isinstance(p, np.ndarray)
-        )
-        return jnp.stack([zero if p is None else p for p in prevs])
+        frames = images if len(rows) == len(members) else images[np.asarray(rows)]
+        has_delta = np.array([members[i][0].frame_idx > 0 for i in rows])
+        state = members[0][0]._row[0]
+        deltas, signed = _gate_rows(state, rows, frames, self.stats)
+        return rows, deltas, has_delta, signed
 
     def _model_head_pass(
-        self, launch: dict, members: list, h_o: int, w_o: int
+        self, launch: dict, members: list, keep: np.ndarray | None,
+        h_o: int, w_o: int,
     ) -> None:
         """Skip-aware digital head for model configurations of one group.
 
         For every :class:`repro.fpca.ProgrammedModel` slice of the fused
-        launch: patch each member stream's kept windows into its previous
-        effective activation map (per-config masks when the stream gates per
-        config) and dispatch the head on the patched maps — ONE batched,
-        non-blocking call per model config, so the double-buffered overlap
-        is preserved.  An all-skipped tick patches nothing and reproduces
+        launch: patch the kept windows (``keep``, the gate's window grids,
+        or a per-config stream's own config's) into the group state's
+        ``(n, h_o, w_o, C)`` effective maps (zeros before any stream of the
+        group has served the config) and dispatch the head on the
+        patched maps — ONE batched, non-blocking call, so the
+        double-buffered overlap is preserved; the patched maps replace the
+        state's whole.  An all-skipped tick patches nothing and reproduces
         the previous logits exactly.
 
-        **Shared-head fusion** (``fuse_shared_heads``): model configs of one
-        launch binding the SAME model signature (zoo archs sharing a head
-        graph, A/B weight variants) collapse into ONE vmapped head pass over
-        all stacked (config, stream) rows — each row binds its own config's
-        head parameters.  The patched-head math is row-independent, so fused
-        and per-config results are bit-identical (pinned in the zoo tests).
+        Model configs of one launch binding the SAME model signature (zoo
+        archs sharing a head graph, A/B weight variants) share ONE vmapped
+        head pass over their concatenated (config, stream) rows, each row
+        binding its own config's head parameters.  The patched-head math is
+        row-independent, so the result is bit-identical to one call per
+        config (pinned in the zoo tests).
         """
         counts = launch["counts"]
+        state = members[0][0]._row[0]
+        n = len(members)
+        base = np.ones((n, h_o, w_o), bool) if keep is None else keep
+        per_config = [j for j, (s, _) in enumerate(members) if s.per_config]
         logits_by_config: dict[str, Any] = {}
         detect_by_config: dict[str, int] = {}
-        model_slices: list[tuple] = []
+        groups: dict[tuple, list[tuple]] = {}
         for name, lo, hi in launch["slices"]:
             cfg = self.pipeline._configs[name]
             if not isinstance(cfg, ProgrammedModel):
                 continue
-            model_slices.append((name, lo, hi, cfg))
-            dc = cfg.model.detect_classes
-            if dc is not None:
-                detect_by_config[name] = dc
-        if not model_slices:
-            return
+            groups.setdefault(cfg.model.signature(), []).append(
+                (name, counts if lo is None else counts[..., lo:hi], cfg)
+            )
+            if name not in state.eff:       # no stream has served it yet
+                state.eff[name] = jnp.zeros(
+                    (n, h_o, w_o, cfg.out_channels), jnp.float32
+                )
+            if cfg.model.detect_classes is not None:
+                detect_by_config[name] = cfg.model.detect_classes
 
-        def gather(name, lo, hi, cfg):
-            sliced = counts if lo is None else counts[..., lo:hi]
-            prevs, keeps = [], []
-            for session, _ in members:
-                prev = session._eff.get(name)
-                if prev is None:
-                    prev = jnp.zeros((h_o, w_o, cfg.out_channels), jnp.float32)
-                prevs.append(prev)
-                st = session.state_for(name)
-                if session.gating and st is not None and st.last_window_mask is not None:
-                    keeps.append(st.last_window_mask)
-                else:
-                    keeps.append(np.ones((h_o, w_o), bool))
-            return sliced, prevs, keeps
+        def keep_for(name):
+            if not per_config:
+                return base
+            grid = base.copy()
+            for j in per_config:
+                grid[j] = members[j][0].state_for(name).last_window_mask
+            return grid
 
-        groups: dict[tuple, list[tuple]] = {}
-        for item in model_slices:
-            groups.setdefault(item[3].model.signature(), []).append(item)
-        n = len(members)
         for group in groups.values():
-            handle = self.pipeline.model_handle_for(group[0][3].model)
-            if len(group) == 1 or not self.fuse_shared_heads:
-                for name, lo, hi, cfg in group:
-                    sliced, prevs, keeps = gather(name, lo, hi, cfg)
-                    keep = np.stack(keeps)
-                    self.stats.h2d_bytes += keep.nbytes
-                    logits, eff = handle.patched_logits(
-                        sliced, jnp.stack(prevs), keep,
-                        head_params=cfg.head_params,
-                    )
-                    for row, (session, _) in enumerate(members):
-                        session._eff[name] = eff[row]
-                    logits_by_config[name] = logits
-            else:
-                # config-major row stacking: rows [g*n, (g+1)*n) are group
-                # member g's streams, each row binding g's head params
-                rows_c, rows_p, rows_k, hp_rows = [], [], [], []
-                for name, lo, hi, cfg in group:
-                    sliced, prevs, keeps = gather(name, lo, hi, cfg)
-                    rows_c.append(sliced)
-                    rows_p.extend(prevs)
-                    rows_k.extend(keeps)
-                    hp_rows.extend([cfg.head_params] * n)
-                hp_stack = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack(xs), *hp_rows
+            handle = self.pipeline.model_handle_for(group[0][2].model)
+            grid = np.concatenate([keep_for(name) for name, _, _ in group])
+            self.stats.h2d_bytes += grid.nbytes
+            if len(group) == 1:
+                name, sliced, cfg = group[0]
+                logits_by_config[name], state.eff[name] = handle.patched_logits(
+                    sliced, state.eff[name], grid, head_params=cfg.head_params
                 )
-                keep = np.stack(rows_k)
-                self.stats.h2d_bytes += keep.nbytes
-                logits, eff = handle.fused_patched_logits(
-                    hp_stack,
-                    jnp.concatenate(rows_c, axis=0),
-                    jnp.stack(rows_p),
-                    keep,
-                )
-                self.stats.fused_head_calls += 1
-                for g, (name, lo, hi, cfg) in enumerate(group):
-                    base = g * n
-                    for row, (session, _) in enumerate(members):
-                        session._eff[name] = eff[base + row]
-                    logits_by_config[name] = logits[base:base + n]
+                continue
+            # config-major rows: [g*n, (g+1)*n) are config g's streams,
+            # each row binding g's head params
+            hp_stack = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs),
+                *[cfg.head_params for _, _, cfg in group for _ in range(n)],
+            )
+            logits, eff = handle.fused_patched_logits(
+                hp_stack,
+                jnp.concatenate([sliced for _, sliced, _ in group]),
+                jnp.concatenate([state.eff[name] for name, _, _ in group]),
+                grid,
+            )
+            self.stats.fused_head_calls += 1
+            for g, (name, _, _) in enumerate(group):
+                logits_by_config[name] = logits[g * n:(g + 1) * n]
+                state.eff[name] = eff[g * n:(g + 1) * n]
         if logits_by_config:
             launch["logits"] = logits_by_config
         if detect_by_config:
@@ -1373,8 +1343,6 @@ class StreamServer:
         session._segment_state = seg.state
         cfg = self.pipeline._configs[name]
         is_model = isinstance(cfg, ProgrammedModel)
-        if is_model:
-            session._eff[name] = seg.state.eff
         session.absorb_segment(seg)
         # a boundary servo step retunes the threshold for the NEXT segment —
         # the traced gate args pick it up without recompiling
@@ -1435,23 +1403,21 @@ class StreamServer:
         return results
 
     def _state_from_session(self, session: StreamSession, name: str):
-        """Segment carry seeded from per-tick host state, so a stream that
-        served ticks through :meth:`run` can continue in segment mode."""
+        """Segment carry seeded from the stream's row of its group state
+        (device arrays, no host copy) and its host gate state, so a stream
+        that served ticks through :meth:`run` can continue in segment
+        mode."""
         from repro.fpca.executable import SegmentState
 
         spec = session.spec
-        prev = session._prev
+        group, j = session._row
         st = session._primary
         bh = math.ceil(spec.eff_h / spec.skip_block)
         bw = math.ceil(spec.eff_w / spec.skip_block)
         hyst = session.gate.hysteresis if session.gate is not None else 0
         state = SegmentState(
-            has_prev=prev is not None,
-            prev_eff=(
-                prev
-                if prev is not None
-                else np.zeros((spec.eff_h, spec.eff_w), np.float32)
-            ),
+            has_prev=session.gating,
+            prev_eff=group.prev[j],
             age=(
                 st.age if st is not None
                 else np.full((bh, bw), hyst + 1, np.int64)
@@ -1460,16 +1426,12 @@ class StreamServer:
         )
         cfg = self.pipeline._configs[name]
         if isinstance(cfg, ProgrammedModel):
-            h_o, w_o = mapping.output_dims(spec)
-            eff = session._eff.get(name)
-            if eff is None:
-                eff = jnp.zeros((h_o, w_o, cfg.out_channels), jnp.float32)
-            state.eff = eff
+            state.eff = group.eff[name][j]
             # the scan's quiet-tick branch replays the carried logits; the
             # host path recomputes head(eff) each tick, which is the same bits
             handle = self.pipeline.model_handle_for(cfg.model)
             state.logits = handle.head_logits(
-                jnp.asarray(eff)[None], head_params=cfg.head_params
+                state.eff[None], head_params=cfg.head_params
             )[0]
         return state
 

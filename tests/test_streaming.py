@@ -14,9 +14,11 @@ Contracts pinned here:
   for many cameras) matches looped single-stream serving bit-for-bit.
 * **Cross-config batching** — configs sharing a compile signature merge into
   one channel-stacked call with unchanged per-request results.
-* **Device-resident gate** — with the frames copied up once a tick and the
-  previous effective frames kept on the device, gate decisions, counts,
-  logits and event packets equal a host-roundtrip gate's bit for bit.
+* **Device-resident group state** — with the frames copied up once a tick
+  and each group's previous effective frames and effective maps kept in
+  one device state, gate decisions, counts, logits and event packets equal
+  those of a reference that keeps every stream's state as host arrays,
+  bit for bit.
 """
 
 from __future__ import annotations
@@ -32,6 +34,7 @@ from repro.core import analysis, gating
 from repro.core.fpca_sim import fpca_forward
 from repro.core.mapping import FPCASpec, active_window_mask, output_dims
 from repro.data.pipeline import SyntheticMovingObject
+from repro.fpca.program import ProgrammedModel
 from repro.kernels.fpca_conv.ops import fpca_conv, window_bucket
 from repro.serving.fpca_pipeline import FPCAPipeline, FrontendRequest
 from repro.serving.saliency import saliency_mask
@@ -40,7 +43,7 @@ from repro.serving.streaming import (
     GateControllerConfig,
     StreamServer,
     StreamSession,
-    _block_reduce_mean,
+    _state_of,
     block_delta_mask,
 )
 
@@ -898,46 +901,128 @@ def test_compiled_stream_matches_server_solo_dense(bucket_model):
 # ---------------------------------------------------------------------------
 
 
+def _block_reduce_mean(x: np.ndarray, block: int) -> np.ndarray:
+    """Mean over ``block x block`` tiles in numpy (ragged edge tiles average
+    their real pixels only), shape ``(ceil(h/b), ceil(w/b))``."""
+    h, w = x.shape
+    bh, bw = -(-h // block), -(-w // block)
+    padded = np.zeros((bh * block, bw * block), x.dtype)
+    padded[:h, :w] = x
+    sums = padded.reshape(bh, block, bw, block).sum((1, 3))
+    ones = np.zeros((bh * block, bw * block), np.float32)
+    ones[:h, :w] = 1.0
+    counts = ones.reshape(bh, block, bw, block).sum((1, 3))
+    return sums / counts
+
+
 class _HostRoundTripServer(StreamServer):
-    """The reference gate with its pixels on the host: each stream's
-    previous effective frame is a host array, stacked with the frames and
-    copied up for one batched step (the solo kernel for a lone stream),
-    and the new effective frames are read back; the event polarity is the
-    numpy block mean of the host difference."""
+    """The reference with every per-stream state on the host, one array per
+    stream: its previous effective frame, and per model config its
+    effective activation map.  The gate stacks the previous frames with the
+    frames for one batched step (the solo kernel for a lone stream) and
+    reads the new effective frames back; the event polarity is the numpy
+    block mean of the host difference.  The head stacks the group's maps
+    for one patched call and splits the result back into per-stream host
+    arrays.  A segment starts from these arrays and leaves its carry in
+    them."""
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.host_prev: dict[str, np.ndarray] = {}
+        self.host_eff: dict[tuple[str, str], np.ndarray] = {}
 
     def _gate_batch(self, members, images, spec):
         kernels = gating.host_gate_kernels(spec)
         rows = [i for i, (s, _) in enumerate(members) if s.gating]
-        prior = [i for i in rows if members[i][0]._prev is not None]
+        ids = {i: members[i][0].stream_id for i in rows}
+        prior = [i for i in rows if ids[i] in self.host_prev]
         if len(prior) > 1:
             curs, deltas = kernels.step_batch(
-                np.stack([members[i][0]._prev for i in prior]),
+                np.stack([self.host_prev[ids[i]] for i in prior]),
                 np.stack([members[i][1] for i in prior]),
             )
             pre = {i: (curs[j], deltas[j]) for j, i in enumerate(prior)}
         else:
-            pre = {i: kernels.step(members[i][0]._prev, members[i][1])
+            pre = {i: kernels.step(self.host_prev[ids[i]], members[i][1])
                    for i in prior}
         none = np.zeros(gating.block_grid(spec), np.float32)
-        currents, deltas, signed = [], [], []
+        deltas, signed = [], []
         for i in rows:
             session, frame = members[i]
             if i not in pre:
-                currents.append(np.asarray(kernels.eff(frame)))
+                self.host_prev[ids[i]] = np.asarray(kernels.eff(frame))
                 deltas.append(none)
                 signed.append(none)
                 continue
             cur, delta = (np.asarray(a) for a in pre[i])
-            currents.append(cur)
             deltas.append(delta)
             signed.append(
-                _block_reduce_mean(cur - session._prev, spec.skip_block)
+                _block_reduce_mean(cur - self.host_prev[ids[i]], spec.skip_block)
                 if session.want_events else none
             )
+            self.host_prev[ids[i]] = cur
         events = any(members[i][0].want_events for i in rows)
-        return (rows, currents, np.stack(deltas),
-                np.array([i in pre for i in rows]),
+        return (rows, np.stack(deltas), np.array([i in pre for i in rows]),
                 np.stack(signed) if events else None)
+
+    def _model_head_pass(self, launch, members, keep, h_o, w_o):
+        counts = launch["counts"]
+        logits = {}
+        for name, lo, hi in launch["slices"]:
+            cfg = self.pipeline._configs[name]
+            if not isinstance(cfg, ProgrammedModel):
+                continue
+            zero = np.zeros((h_o, w_o, cfg.out_channels), np.float32)
+            keys, windows = [], []
+            for session, _ in members:
+                keys.append((session.stream_id, name))
+                st = session.state_for(name)
+                windows.append(st.last_window_mask if session.gating
+                               else np.ones((h_o, w_o), bool))
+            # one call over the group's rows: a batch-1 head call is not
+            # bit-equal to a batched one on every backend
+            lg, eff = self.pipeline.model_handle_for(cfg.model).patched_logits(
+                counts if lo is None else counts[..., lo:hi],
+                np.stack([self.host_eff.get(k, zero) for k in keys]),
+                np.stack(windows), head_params=cfg.head_params,
+            )
+            eff = np.asarray(eff)
+            for row, k in enumerate(keys):
+                self.host_eff[k] = eff[row]
+            logits[name] = lg
+        if logits:
+            launch["logits"] = logits
+
+    def _state_from_session(self, session, name):
+        from repro.fpca.executable import SegmentState
+
+        spec = session.spec
+        prev = self.host_prev.get(session.stream_id)
+        state = SegmentState(
+            has_prev=prev is not None,
+            prev_eff=(np.zeros((spec.eff_h, spec.eff_w), np.float32)
+                      if prev is None else prev),
+            age=session._primary.age, frame_idx=session.frame_idx,
+        )
+        cfg = self.pipeline._configs[name]
+        if isinstance(cfg, ProgrammedModel):
+            h_o, w_o = output_dims(spec)
+            state.eff = self.host_eff.get(
+                (session.stream_id, name),
+                np.zeros((h_o, w_o, cfg.out_channels), np.float32),
+            )
+            state.logits = self.pipeline.model_handle_for(cfg.model).head_logits(
+                state.eff[None], head_params=cfg.head_params)[0]
+        return state
+
+    def run_segment(self, stream_id, frames, **kwargs):
+        out = super().run_segment(stream_id, frames, **kwargs)
+        session = self.sessions[stream_id]
+        carry = session._segment_state
+        self.host_prev[stream_id] = np.asarray(carry.prev_eff, np.float32)
+        if carry.eff is not None:
+            self.host_eff[(stream_id, session.config)] = np.asarray(carry.eff)
+        return out
 
 
 def _model_program(spec):
@@ -1089,14 +1174,16 @@ def test_gate_row_counters_count_resident_and_restacked(resident_pipe):
     server.run_segment("c2", np.stack([next(frames) for _ in range(2)]))
     assert run(("c2", "c0")) == (9, 11)                   # segment replaced c2
     assert run(("c2", "c0")) == (11, 11)
-    # a stream stepped alone keeps its own device array
+    # a stream stepped alone holds a one-row state of its own
     session = StreamSession("solo", "cls", _spec(), RESIDENT_GATE,
                             stats=server.stats)
     for _ in range(3):
         session.step(next(frames))
+        state, row = session._row
+        assert state.sessions == [session] and row == 0
+        assert state.prev.shape == (1, _spec().eff_h, _spec().eff_w)
     assert (server.stats.gate_rows_resident,
             server.stats.gate_rows_restacked) == (13, 12)
-    assert isinstance(session._prev, np.ndarray)
 
 
 def test_steady_h2d_bytes_are_frame_plus_two_keep_grids(resident_pipe):
@@ -1126,24 +1213,47 @@ def test_steady_h2d_bytes_are_frame_plus_two_keep_grids(resident_pipe):
 
 
 def test_streams_left_behind_keep_their_own_row(resident_pipe):
-    """A stream that sits out a tick keeps its previous frame as its own
-    device row, not the whole stack its group left behind, and resumes
-    from it bit-identically."""
-    server = StreamServer(resident_pipe, RESIDENT_GATE)
-    for sid in ("c0", "c1", "c2"):
-        server.add_stream(sid, "cls")
+    """A stream that sits out a tick holds a one-row state of its own, with
+    its previous frame and its effective map, not the state its group left
+    behind, and resumes from it bit-identically."""
+    ids = ("c0", "c1", "c2")
     cam = SyntheticMovingObject((H, W), seed=8, radius=4.0)
-    frames = iter(cam.frame_at(t) for t in range(16))
-    list(server.run([{sid: next(frames) for sid in ("c0", "c1", "c2")}
-                     for _ in range(2)]))
-    before = server.sessions["c1"]._prev
-    list(server.run([{sid: next(frames) for sid in ("c0", "c2")}]))
+    frames = [cam.frame_at(t) for t in range(5)]
+    ticks = [{sid: frames[t] for sid in ids} for t in range(2)]
+    ticks += [{sid: frames[2] for sid in ("c0", "c2")}]
+    back = [{sid: frames[t] for sid in ids} for t in (3, 4)]
+
+    server = StreamServer(resident_pipe, RESIDENT_GATE)
+    for sid in ids:
+        server.add_stream(sid, "cls")
+    list(server.run(ticks[:2]))
     c1 = server.sessions["c1"]
-    assert isinstance(c1._prev_src, jax.Array)
-    assert c1._prev_src.shape == (_spec().eff_h, _spec().eff_w)
-    np.testing.assert_array_equal(c1._prev, before)
-    stack = server.sessions["c0"]._prev_src[0]
-    assert [s.stream_id for s in stack.sessions] == ["c0", "c2"]
+    group, j = c1._row
+    prev, eff = c1._prev, np.asarray(group.eff["cls"][j])
+    list(server.run(ticks[2:]))
+    state, row = c1._row
+    assert state.sessions == [c1] and row == 0
+    assert state.prev.shape == (1, _spec().eff_h, _spec().eff_w)
+    np.testing.assert_array_equal(c1._prev, prev)
+    np.testing.assert_array_equal(np.asarray(state.eff["cls"][0]), eff)
+    group = server.sessions["c0"]._row[0]
+    assert [s.stream_id for s in group.sessions] == ["c0", "c2"]
+    assert server.sessions["c2"]._row == (group, 1)
+    resumed = [r for rs in server.run(back) for r in rs]
+
+    # the host-state reference served the same ticks
+    ref = _HostRoundTripServer(resident_pipe, RESIDENT_GATE)
+    for sid in ids:
+        ref.add_stream(sid, "cls")
+    list(ref.run(ticks))
+    want = [r for rs in ref.run(back) for r in rs]
+    assert len(resumed) == len(want) == 6
+    for a, b in zip(resumed, want):
+        assert (a.stream_id, a.frame_idx) == (b.stream_id, b.frame_idx)
+        assert a.kept_windows == b.kept_windows
+        np.testing.assert_array_equal(a.block_mask, b.block_mask)
+        np.testing.assert_array_equal(a.counts, b.counts)
+        np.testing.assert_array_equal(a.logits, b.logits)
 
 
 # ---------------------------------------------------------------------------
@@ -1197,10 +1307,10 @@ class _PerStreamGateServer(StreamServer):
 
     def _gate_group(self, members, images, spec, h_o, w_o, gated):
         pre = {}
+        _state_of([session for session, _ in members], self.stats)
         if gated:
-            rows, currents, deltas, has, signed = self._gate_batch(
-                members, images, spec)
-            pre = {i: (currents[j], deltas[j] if has[j] else None,
+            rows, deltas, has, signed = self._gate_batch(members, images, spec)
+            pre = {i: (deltas[j] if has[j] else None,
                        signed[j] if signed is not None and has[j] else None)
                    for j, i in enumerate(rows)}
         entries, keeps = [], []
@@ -1208,7 +1318,7 @@ class _PerStreamGateServer(StreamServer):
             frame_idx = session.frame_idx
             block = window = None
             if session.gating:
-                cur, delta, sgn = pre[row]
+                delta, sgn = pre[row]
                 if session.want_events:
                     session._last_signed = sgn
                 for st in session._states:
@@ -1216,7 +1326,6 @@ class _PerStreamGateServer(StreamServer):
                     block = keep if block is None else block | keep
                     window = (st.last_window_mask if window is None
                               else window | st.last_window_mask)
-                session._prev_src = cur
                 session.last_window_mask = window
             session.frame_idx += 1
             kept = int(window.sum()) if window is not None else h_o * w_o

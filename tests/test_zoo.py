@@ -393,28 +393,34 @@ def test_detect_serve_requests(bucket_model):
 
 
 def test_fused_shared_heads_bit_parity(bucket_model):
+    """Two model configs sharing a model signature on one stream run ONE
+    fused head pass a tick, bit-identical to serving each config alone."""
     spec = _spec()
     model = zoo.build_model({"arch": "fpca_resnet", "spec": spec,
                              "width": 4, "hidden": 8})
     kernel = _kernel(spec)
-    hp_a = model.init_head(jax.random.PRNGKey(3))
-    hp_b = model.init_head(jax.random.PRNGKey(4))
+    hp = {"a": model.init_head(jax.random.PRNGKey(3)),
+          "b": model.init_head(jax.random.PRNGKey(4))}
     frames = _frames(4, seed=11)
 
-    def serve(fuse: bool):
+    def serve(configs):
         pipe = FPCAPipeline(bucket_model, backend="basis")
-        pipe.register("a", model, kernel, head_params=hp_a)
-        pipe.register("b", model, kernel, head_params=hp_b)
-        srv = StreamServer(pipe, fpca.DeltaGateConfig(threshold=0.05),
-                           fuse_shared_heads=fuse)
-        srv.add_stream("s", ["a", "b"])
+        for name in configs:
+            pipe.register(name, model, kernel, head_params=hp[name])
+        srv = StreamServer(pipe, fpca.DeltaGateConfig(threshold=0.05))
+        srv.add_stream("s", list(configs))
         return list(srv.serve("s", frames)), srv
 
-    fused, srv_f = serve(True)
-    plain, srv_p = serve(False)
+    fused, srv_f = serve(("a", "b"))
     assert srv_f.stats.fused_head_calls == len(frames)
-    assert srv_p.stats.fused_head_calls == 0
-    for x, y in zip(fused, plain):
+    alone = {}
+    for name in ("a", "b"):
+        out, srv = serve((name,))
+        assert srv.stats.fused_head_calls == 0
+        alone[name] = out
+    assert len(fused) == 2 * len(frames)
+    for x in fused:
+        y = alone[x.config][x.frame_idx]
         assert (x.config, x.frame_idx) == (y.config, y.frame_idx)
         np.testing.assert_array_equal(x.logits, y.logits)
 
